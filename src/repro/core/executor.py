@@ -553,13 +553,24 @@ class StagedExecutable:
         return "\n".join(lines)
 
 
+def host_device_map(plan: Plan) -> Optional[List[Any]]:
+    """Logical plan device *i* -> ``jax.devices()[i]``: the map a
+    serving caller hands :func:`build_executable`.  None where the host
+    has fewer devices than the plan (single-device validation mode)."""
+    devs = jax.devices()
+    n = len(plan.devices)
+    return list(devs[:n]) if len(devs) >= n else None
+
+
 def build_executable(traced: TracedGraph, plan: Plan,
                      device_map: Optional[Sequence[Any]] = None
                      ) -> StagedExecutable:
     """Compile a traced graph + plan into a disaggregated executable.
 
     When ``device_map`` is None all stages run on the default device —
-    useful for validating the stage decomposition itself.
+    useful for validating the stage decomposition itself.  Serving
+    callers pass :func:`host_device_map`, which puts logical device *i*
+    on the host's device *i*.
     """
     if device_map is None:
         d = jax.devices()[0]

@@ -152,7 +152,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    length = len_ref[0]
+    length = len_ref[pl.program_id(0)]
     k_start = ki * block_k
 
     @pl.when(k_start < length)
@@ -200,26 +200,31 @@ def flash_attention_decode(q, k, v, lengths, *, sm_scale=None,
 
     kernel = functools.partial(_decode_kernel, sm_scale=scale,
                                block_k=block_k, n_k=n_k)
-    out = pl.pallas_call(
-        kernel,
+    # lengths ride in SMEM as a scalar-prefetch operand: the kernel reads
+    # its row's length without a (1,)-shaped VMEM block
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, H, n_k),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, ki: (b,)),
             pl.BlockSpec((None, None, 1, D),
-                         lambda b, h, ki: (b, h, 0, 0)),
+                         lambda b, h, ki, lens: (b, h, 0, 0)),
             pl.BlockSpec((None, None, block_k, D),
-                         lambda b, h, ki: (b, h // rep, ki, 0)),
+                         lambda b, h, ki, lens: (b, h // rep, ki, 0)),
             pl.BlockSpec((None, None, block_k, D),
-                         lambda b, h, ki: (b, h // rep, ki, 0)),
+                         lambda b, h, ki, lens: (b, h // rep, ki, 0)),
         ],
         out_specs=pl.BlockSpec((None, None, 1, D),
-                               lambda b, h, ki: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
+                               lambda b, h, ki, lens: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((1,), jnp.float32),
             pltpu.VMEM((1,), jnp.float32),
             pltpu.VMEM((1, D), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
         interpret=interpret,
     )(lengths.astype(jnp.int32), qf, k, v)
     return out.reshape(B, H, D)

@@ -8,12 +8,7 @@ import jax
 from repro.kernels.mamba2_ssd import kernel as K
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd(xh, B_, C_, a_log, *, chunk: int = 128, interpret=None):
-    itp = _default_interpret() if interpret is None else interpret
+def ssd(xh, B_, C_, a_log, *, chunk: int = 128, interpret: bool = False):
     return K.ssd_chunk_scan(xh, B_, C_, a_log, chunk=chunk,
-                            interpret=itp)
+                            interpret=interpret)

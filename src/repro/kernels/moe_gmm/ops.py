@@ -10,14 +10,9 @@ from repro.kernels.moe_gmm import kernel as K
 pad_groups = K.pad_groups
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n",
                                              "interpret"))
 def gmm(x, w, tile_gid, *, block_m: int = 128, block_n: int = 128,
-        interpret=None):
-    itp = _default_interpret() if interpret is None else interpret
+        interpret: bool = False):
     return K.gmm(x, w, tile_gid, block_m=block_m, block_n=block_n,
-                 interpret=itp)
+                 interpret=interpret)

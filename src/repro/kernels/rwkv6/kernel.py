@@ -9,7 +9,10 @@ state never round-trips HBM (the whole point of the kernel), and chunk
 blocks stream r/k/v/w tiles HBM->VMEM.
 
 This mirrors how the official CUDA kernel works (sequential inner loop,
-state in shared memory), adapted to Pallas refs + grid carry.
+state in shared memory), adapted to Pallas refs + grid carry.  The
+wrapper lays tokens out head-major, (B, H, S, P), so every token block
+ends in (chunk, P); each token is read as a (1, P) row, and the rows
+needed as columns are turned by a masked lane reduction.
 """
 from __future__ import annotations
 
@@ -29,18 +32,26 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
     def _init():
         state_ref[...] = s0_ref[...].astype(jnp.float32)
 
-    u = u_ref[...].astype(jnp.float32)               # (P,)
+    u = u_ref[...].astype(jnp.float32)               # (P, 1)
+    P = u.shape[0]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (P, P), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (P, P), 1))
+
+    def column(row):
+        # (1, P) -> (P, 1) by a masked lane reduction (no transpose)
+        return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
 
     def step(t, _):
-        rt = r_ref[t, :].astype(jnp.float32)         # (P,)
-        kt = k_ref[t, :].astype(jnp.float32)
-        vt = v_ref[t, :].astype(jnp.float32)
-        wt = w_ref[t, :].astype(jnp.float32)
+        tok = pl.ds(t, 1)
+        rt = column(r_ref[tok, :].astype(jnp.float32))   # (P, 1)
+        kt = column(k_ref[tok, :].astype(jnp.float32))   # (P, 1)
+        wt = column(w_ref[tok, :].astype(jnp.float32))   # (P, 1)
+        vt = v_ref[tok, :].astype(jnp.float32)           # (1, P)
         s = state_ref[...]                           # (P, P)
-        kv = kt[:, None] * vt[None, :]               # (P, P)
-        y = ((s + u[:, None] * kv) * rt[:, None]).sum(axis=0)
-        y_ref[t, :] = y.astype(y_ref.dtype)
-        state_ref[...] = wt[:, None] * s + kv
+        kv = kt * vt                                 # (P, P)
+        y = jnp.sum((s + u * kv) * rt, axis=0, keepdims=True)
+        y_ref[tok, :] = y.astype(y_ref.dtype)
+        state_ref[...] = wt * s + kv
         return 0
 
     jax.lax.fori_loop(0, chunk, step, 0)
@@ -58,29 +69,31 @@ def wkv(r, k, v, w, u, state, *, chunk=64, interpret=False):
     assert S % chunk == 0
     nC = S // chunk
 
-    def tok_spec():
-        # (B, S, H, P) -> block (chunk, P) at (b, c, h)
-        return pl.BlockSpec((None, chunk, None, P),
-                            lambda b, h, c: (b, c, h, 0))
+    def heads_major(x):
+        # (B, S, H, P) -> (B, H, S, P): blocks end in (chunk, P)
+        return jnp.moveaxis(x, 2, 1).astype(jnp.float32)
 
+    tok_spec = pl.BlockSpec((None, None, chunk, P),
+                            lambda b, h, c: (b, h, c, 0))
     kernel = functools.partial(_wkv_kernel, chunk=chunk, n_chunks=nC)
     y, sT = pl.pallas_call(
         kernel,
         grid=(B, H, nC),
         in_specs=[
-            tok_spec(), tok_spec(), tok_spec(), tok_spec(),
-            pl.BlockSpec((None, P), lambda b, h, c: (h, 0)),
+            tok_spec, tok_spec, tok_spec, tok_spec,
+            pl.BlockSpec((None, P, 1), lambda b, h, c: (h, 0, 0)),
             pl.BlockSpec((None, None, P, P), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_specs=[
-            tok_spec(),
+            tok_spec,
             pl.BlockSpec((None, None, P, P), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, S, H, P), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, S, P), jnp.float32),
             jax.ShapeDtypeStruct((B, H, P, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, P), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, w, u, state)
-    return y, sT
+    )(heads_major(r), heads_major(k), heads_major(v), heads_major(w),
+      u[..., None], state)
+    return jnp.moveaxis(y, 1, 2), sT

@@ -8,11 +8,6 @@ import jax
 from repro.kernels.rwkv6 import kernel as K
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv(r, k, v, w, u, state, *, chunk: int = 64, interpret=None):
-    itp = _default_interpret() if interpret is None else interpret
-    return K.wkv(r, k, v, w, u, state, chunk=chunk, interpret=itp)
+def wkv(r, k, v, w, u, state, *, chunk: int = 64, interpret: bool = False):
+    return K.wkv(r, k, v, w, u, state, chunk=chunk, interpret=interpret)

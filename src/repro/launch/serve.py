@@ -19,6 +19,7 @@ import jax
 import numpy as np
 
 import repro.configs as configs
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as M
 from repro.serving.engine import Request, ServingEngine
 
@@ -66,6 +67,7 @@ def main() -> None:
                          "flags (--arch/--slots/... are ignored except "
                          "--smoke/--requests/--max-new/--trace/--rate)")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.deployment:
         from repro.serving.spec import DeploymentSpec
@@ -91,7 +93,7 @@ def main() -> None:
     if args.disaggregate:
         from repro.core import analyzer, planner
         from repro.core.costmodel import TPU_V5E, TPU_V5P
-        from repro.core.executor import build_executable
+        from repro.core.executor import build_executable, host_device_map
         import jax.numpy as jnp
         cache = M.init_cache(cfg, args.slots, args.max_len)
         toks = jnp.zeros((args.slots, 1), jnp.int32)
@@ -106,7 +108,14 @@ def main() -> None:
         traced = traced.with_graph(g)
         plan = planner.plan(g, [TPU_V5P, TPU_V5E], policy=args.policy)
         print(plan.summary())
-        exe = build_executable(traced, plan)
+        # logical device i runs on the host's device i; a host with
+        # fewer devices than the plan validates it on one device
+        device_map = host_device_map(plan)
+        if device_map is None:
+            print(f"{len(jax.devices())} device(s) for a "
+                  f"{len(plan.devices)}-device plan: every stage runs "
+                  f"on {jax.devices()[0]}")
+        exe = build_executable(traced, plan, device_map)
         decode_fn = lambda p, c, t, q: exe(p, c, t, q)
 
     reqs = _build_requests(args, cfg, args.max_len)

@@ -48,6 +48,7 @@ syncs).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -103,6 +104,65 @@ class EngineStats:
             "mean_norm_latency": float(np.mean(self.latency_per_token))
             if self.latency_per_token else 0.0,
         }
+
+
+# --------------------------------------------------------------------- #
+# The engine's device programs.  Pure functions of (params, cache, ...)
+# with the config and sampling constants static: the weights enter every
+# compiled program as arguments, never as baked-in constants.
+# --------------------------------------------------------------------- #
+def _post(logits, last_tok, pos, budget, active, key, *, eos: int,
+          temp: float, max_len: int):
+    """Sampling + termination, fused with the decode dispatch.
+
+    ``packed`` is one (2, slots) int32 array — [emitted token or -1;
+    done flag] — so each step leaves exactly one buffer for the sync to
+    fetch (no eager stacking on the hot path).
+    """
+    if temp <= 0.0:
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    else:
+        key, sub = jax.random.split(key)
+        tok = jax.random.categorical(sub, logits / temp,
+                                     axis=-1).astype(jnp.int32)
+    new_pos = jnp.where(active, pos + 1, pos)
+    new_budget = jnp.where(active, budget - 1, budget)
+    done = active & ((new_budget <= 0) | (tok == eos)
+                     | (new_pos >= max_len - 1))
+    new_active = active & ~done
+    new_last = jnp.where(new_active, tok, last_tok)
+    emit = jnp.where(active, tok, -1)     # -1 = idle slot
+    packed = jnp.stack([emit, done.astype(jnp.int32)])
+    return new_last, new_pos, new_budget, new_active, packed, key
+
+
+post_step = jax.jit(_post, static_argnames=("eos", "temp", "max_len"))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("cfg", "eos", "temp", "max_len"))
+def decode_fused(params, cache, last_tok, pos, budget, active, key, *,
+                 cfg: ModelConfig, eos: int, temp: float, max_len: int):
+    """One decode step over every slot, with sampling and termination."""
+    logits, cache = M.decode_step(params, cfg, last_tok[:, None], cache,
+                                  pos)
+    return (cache,) + _post(logits, last_tok, pos, budget, active, key,
+                            eos=eos, temp=temp, max_len=max_len)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def prefill_batch(params, cache, tokens, last_pos, *, cfg: ModelConfig):
+    """Whole-prompt prefill of a right-padded admission batch."""
+    return M.prefill(params, cfg, tokens, cache, last_pos=last_pos)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def prefill_chunk(params, cache, tokens, offset, last_pos, *,
+                  cfg: ModelConfig):
+    """One chunk of an incremental prefill (``offset`` is traced, so
+    every full-size chunk shares one compile)."""
+    return M.prefill(params, cfg, tokens, cache, offset=offset,
+                     last_pos=last_pos)
 
 
 class ServingEngine:
@@ -163,58 +223,30 @@ class ServingEngine:
                 "kv_pool_blocks requires kv_block_tokens"
             self._paged = None
         self.sessions = SessionManager(self)
-
-        eos = -1 if eos_id is None else int(eos_id)
-        temp = float(temperature)
-        greedy = temp <= 0.0
-
-        def _sample(logits, key):
-            if greedy:
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32), key
-            key, sub = jax.random.split(key)
-            tok = jax.random.categorical(sub, logits / temp, axis=-1)
-            return tok.astype(jnp.int32), key
-
-        def _post(logits, last_tok, pos, budget, active, key):
-            """Sampling + termination, fused with the decode dispatch.
-
-            ``packed`` is one (2, slots) int32 array — [emitted token or
-            -1; done flag] — so each step leaves exactly one buffer for
-            the sync to fetch (no eager stacking on the hot path).
-            """
-            tok, key = _sample(logits, key)
-            new_pos = jnp.where(active, pos + 1, pos)
-            new_budget = jnp.where(active, budget - 1, budget)
-            done = active & ((new_budget <= 0) | (tok == eos)
-                             | (new_pos >= max_len - 1))
-            new_active = active & ~done
-            new_last = jnp.where(new_active, tok, last_tok)
-            emit = jnp.where(active, tok, -1)     # -1 = idle slot
-            packed = jnp.stack([emit, done.astype(jnp.int32)])
-            return new_last, new_pos, new_budget, new_active, packed, key
-
-        self._post = jax.jit(_post)
         self._decode_custom = decode_fn
-        if decode_fn is None:
-            # params are engine-lifetime constants: close over them so
-            # the hot loop does not re-flatten / re-validate the param
-            # pytree on every dispatch.
-            def _fused(c, last_tok, pos, budget, active, key):
-                logits, c = M.decode_step(params, cfg, last_tok[:, None],
-                                          c, pos)
-                return (c,) + _post(logits, last_tok, pos, budget,
-                                    active, key)
-            self._step_fused = jax.jit(_fused)
         self._prefill_custom = prefill_fn
-        if prefill_fn is None:
-            self._prefill = jax.jit(
-                lambda c, t, lp: M.prefill(params, cfg, t, c,
-                                           last_pos=lp))
-            # one chunk of an incremental prefill (offset is a traced
-            # scalar, so every full-size chunk shares one compile)
-            self._prefill_at = jax.jit(
-                lambda c, t, off, lp: M.prefill(params, cfg, t, c,
-                                                offset=off, last_pos=lp))
+        self._sampling = dict(eos=-1 if eos_id is None else int(eos_id),
+                              temp=float(temperature), max_len=max_len)
+
+    # ------------------------------------------------------------------ #
+    # Jitted steps with this engine's params bound (the programs take
+    # params as an argument, so engines sharing params share one copy)
+    # ------------------------------------------------------------------ #
+    def _post(self, logits, last_tok, pos, budget, active, key):
+        return post_step(logits, last_tok, pos, budget, active, key,
+                         **self._sampling)
+
+    def _step_fused(self, cache, last_tok, pos, budget, active, key):
+        return decode_fused(self.params, cache, last_tok, pos, budget,
+                            active, key, cfg=self.cfg, **self._sampling)
+
+    def _prefill(self, cache, toks, last_pos):
+        return prefill_batch(self.params, cache, toks, last_pos,
+                             cfg=self.cfg)
+
+    def _prefill_at(self, cache, toks, offset, last_pos):
+        return prefill_chunk(self.params, cache, toks, offset, last_pos,
+                             cfg=self.cfg)
 
     # ------------------------------------------------------------------ #
     def _now(self, now: Optional[float]) -> float:

@@ -112,7 +112,10 @@ def test_crash_recovery_zero_lost_bit_identical(arch_setup):
 
     chaos = _reqs(cfg, max_new=12)
     dep = spec.compile().launch(cfg, params)
-    dep.inject(FaultPlan(seed=4).crash(0.25, group=0, recover_at=0.6),
+    # group 0 straggles until the crash, so its sessions are still
+    # mid-decode at t=0.25 however fast the (already compiled) steps run
+    dep.inject(FaultPlan(seed=4).crash(0.25, group=0, recover_at=0.6)
+               .straggle(0.0, 0.25, group=0, factor=8.0),
                recovery=RecoveryConfig(interval=0.02,
                                        min_dirty_tokens=1))
     stats = dep.run(chaos)

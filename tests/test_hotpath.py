@@ -339,3 +339,44 @@ def test_engine_trace_driven():
     assert stats.completed == 6
     assert all(len(r.output) >= 1 for r in reqs)
     assert stats.summary()["mean_tpot"] >= 0.0
+
+
+def _constant_payload_bytes(hlo_text: str):
+    """Bytes of every dense constant literal in a StableHLO module."""
+    import re
+    return [len(m) // 2 - 1 for m in
+            re.findall(r'stablehlo\.constant dense<"(0x[0-9A-Fa-f]*)">',
+                       hlo_text)]
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill", "prefill_chunk"])
+def test_engine_weights_enter_as_arguments(step):
+    """The engine's compiled programs take the weights as arguments: a
+    closed-over weight would be baked into every executable (one copy per
+    prefill bucket on the device)."""
+    import re
+    from repro.serving.engine import (decode_fused, prefill_batch,
+                                      prefill_chunk)
+    cfg = configs.get_smoke("qwen3_1_7b")
+    params = M.init_params(cfg)
+    cache = M.init_cache(cfg, 2, 32)
+    if step == "decode":
+        z = jnp.zeros(2, jnp.int32)
+        lowered = decode_fused.lower(
+            params, cache, z, z, z, jnp.ones(2, bool),
+            jax.random.PRNGKey(0), cfg=cfg, eos=-1, temp=0.0, max_len=32)
+    elif step == "prefill":
+        lowered = prefill_batch.lower(
+            params, cache, jnp.zeros((2, 8), jnp.int32),
+            jnp.zeros(2, jnp.int32), cfg=cfg)
+    else:
+        lowered = prefill_chunk.lower(
+            params, cache, jnp.zeros((2, 8), jnp.int32),
+            jnp.asarray(8, jnp.int32), jnp.zeros(2, jnp.int32), cfg=cfg)
+    text = lowered.as_text()
+    main = re.search(r"func\.func public @main\((.*?)\) ->", text,
+                     re.S).group(1)
+    leaves = jax.tree_util.tree_leaves(params)
+    assert len(re.findall(r"%arg\d+:", main)) >= len(leaves)
+    biggest = max(leaf.nbytes for leaf in leaves)
+    assert max(_constant_payload_bytes(text), default=0) < biggest

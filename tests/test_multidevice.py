@@ -186,7 +186,6 @@ def test_gradient_compression_crosspod_allreduce():
     out = _run("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.launch.mesh import make_mesh
     from repro.train.compress import quantize_int8, dequantize_int8
 
@@ -197,8 +196,8 @@ def test_gradient_compression_crosspod_allreduce():
         y = dequantize_int8(q, s)       # wire format
         return jax.lax.pmean(y, "pod")
 
-    f = shard_map(compressed_allreduce, mesh=mesh,
-                  in_specs=P("pod"), out_specs=P("pod"))
+    f = jax.shard_map(compressed_allreduce, mesh=mesh,
+                      in_specs=P("pod"), out_specs=P("pod"))
     g = jax.random.normal(jax.random.PRNGKey(0), (8, 32))
     out = f(g)
     true_mean = jnp.tile(g.reshape(4, 2, 32).mean(0), (4, 1))

@@ -144,3 +144,35 @@ def test_plan_solver_speed_matches_paper_scale():
     dt = time.perf_counter() - t0
     assert dt < 1.0, f"latency solve took {dt:.3f}s"
     assert len(p.labels) == 500
+
+
+def test_host_device_map_places_logical_devices_on_host_devices():
+    """Serving callers map logical device i to jax.devices()[i]; a host
+    with fewer devices than the plan gets None (validation mode)."""
+    from repro.core.executor import host_device_map
+    traced = _traced_decode()[3]
+    n = len(jax.devices())
+    fits = planner.plan(traced.graph, [TPU_V5E] * n, cache=False)
+    assert host_device_map(fits) == list(jax.devices())
+    short = planner.plan(traced.graph, [TPU_V5E] * (n + 1), cache=False)
+    assert host_device_map(short) is None
+
+
+def test_compile_cache_location(monkeypatch):
+    """The compile cache goes where JAX_COMPILATION_CACHE_DIR says, else
+    to <checkout>/.jax_cache, which git ignores."""
+    from pathlib import Path
+    from repro.launch.compile_cache import use_compile_cache
+    repo = Path(__file__).resolve().parents[1]
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "elsewhere")
+        assert use_compile_cache() is None
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = use_compile_cache()
+        assert path == repo / ".jax_cache"
+        assert jax.config.jax_compilation_cache_dir == str(path)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+    assert ".jax_cache/" in (repo / ".gitignore").read_text().split()

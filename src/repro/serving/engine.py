@@ -43,7 +43,17 @@ Accounting note: completion times are observed at sync boundaries, so a
 request's ``finished`` stamp can be up to ``sync_every - 1`` decode steps
 late.  That is the deliberate trade of the sync-free loop; run with
 ``sync_every=1`` to recover per-token accounting (and per-token host
-syncs).
+syncs).  ``admitted`` is stamped when ``admit_batch`` takes a request,
+before the sync that admission starts with, so ``admitted - arrival`` is
+the request's queue wait: the time it was due while the engine was busy
+elsewhere (a blocked sync, another admission, no free slot).
+
+Host spans: the control plane marks its steps with
+``jax.profiler.TraceAnnotation`` spans named ``engine.*`` (admission and
+its parts, the sync and its parts, the decode dispatch, the wait for the
+next arrival).  They cost about a microsecond each when no profiler
+runs; a profiler trace puts them on the device's clock, so each idle gap
+of the chip can be laid at the host step that held it.
 """
 from __future__ import annotations
 
@@ -55,6 +65,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models import model as M
 from repro.models.config import ModelConfig
@@ -78,16 +89,30 @@ class Request:
     priority: int = 0                   # preemption rank (higher wins)
     # filled by the engine:
     output: List[int] = dataclasses.field(default_factory=list)
+    admitted: float = -1.0              # admit_batch took it (queue wait ends)
     ttft: float = -1.0
     finished: float = -1.0
 
 
 @dataclasses.dataclass
 class EngineStats:
+    """Counters over the engine's life (take differences for a window),
+    and the finished requests' TTFT, TPOT and latency per token.
+
+    * ``completed``: requests finalized (budget, EOS or length).
+    * ``decode_steps``: decode programs dispatched.
+    * ``host_syncs``: non-empty syncs (one device-to-host fetch each).
+    * ``prefill_batches``: admission groups prefilled.
+    * ``prefill_tokens``: real prompt tokens admitted through them.
+    * ``prefill_padded_tokens``: tokens the prefill programs computed
+      (rows x length of the padded batch, pads included).
+    """
     completed: int = 0
     decode_steps: int = 0
     host_syncs: int = 0
     prefill_batches: int = 0
+    prefill_tokens: int = 0
+    prefill_padded_tokens: int = 0
     ttft: List[float] = dataclasses.field(default_factory=list)
     tpot: List[float] = dataclasses.field(default_factory=list)
     latency_per_token: List[float] = dataclasses.field(
@@ -291,48 +316,56 @@ class ServingEngine:
         Returns the number admitted.  Attention families take ONE padded
         prefill for the whole batch; recurrent families are grouped by
         exact prompt length (right-padding would pollute SSM state).
+        Each request taken is stamped ``admitted`` with the time of this
+        call, before its leading sync.
         """
-        # settle any buffered window first: admission must see fresh
-        # slot state, and a slot re-filled mid-window would otherwise
-        # have its new tokens hidden behind the old -1 idle markers
-        self.sync(now)
-        if self._paged is not None:
-            # Paged admission runs in WAVES of up to ``slots`` requests:
-            # each wave prefills into the dense batch, then parks into
-            # the pool to free slots for the next wave — so concurrent
-            # residency is bounded by free BLOCKS, not free slots.
-            left = list(reqs)
-            admitted = 0
-            while left:
-                pairs = self._paged_admit(left, now)
-                if not pairs:
-                    break
-                for group in self._admission_groups(pairs):
-                    self._admit_group(group, now)
-                admitted += len(pairs)
-                left = left[len(pairs):]
-                if left:
-                    self.sync(now)      # settle before parking
-                    wave = {id(r) for _, r in pairs}
-                    for s in range(self.slots):
-                        if self.active[s] is not None \
-                                and id(self.active[s]) in wave:
-                            self._park_slot(s, self._now(now))
-            self._recompute_remaining()
-            return admitted
+        with TraceAnnotation("engine.admit"):
+            t_take = self._now(now)
+            # settle any buffered window first: admission must see fresh
+            # slot state, and a slot re-filled mid-window would otherwise
+            # have its new tokens hidden behind the old -1 idle markers
+            self.sync(now)
+            if self._paged is not None:
+                # Paged admission runs in WAVES of up to ``slots``
+                # requests: each wave prefills into the dense batch, then
+                # parks into the pool to free slots for the next wave —
+                # so concurrent residency is bounded by free BLOCKS, not
+                # free slots.
+                left = list(reqs)
+                admitted = 0
+                while left:
+                    pairs = self._paged_admit(left, now)
+                    if not pairs:
+                        break
+                    for _, r in pairs:
+                        r.admitted = t_take
+                    for group in self._admission_groups(pairs):
+                        self._admit_group(group, now)
+                    admitted += len(pairs)
+                    left = left[len(pairs):]
+                    if left:
+                        self.sync(now)      # settle before parking
+                        wave = {id(r) for _, r in pairs}
+                        for s in range(self.slots):
+                            if self.active[s] is not None \
+                                    and id(self.active[s]) in wave:
+                                self._park_slot(s, self._now(now))
+                self._recompute_remaining()
+                return admitted
 
-        free = [s for s in range(self.slots) if self.active[s] is None]
-        take = list(reqs[:len(free)])
-        for r in take:
-            assert len(r.prompt) < self.max_len, \
-                "prompt exceeds engine max_len"
-        pairs = list(zip(free, take))
-        if not pairs:
-            return 0
-        for group in self._admission_groups(pairs):
-            self._admit_group(group, now)
-        self._recompute_remaining()
-        return len(pairs)
+            free = [s for s in range(self.slots) if self.active[s] is None]
+            take = list(reqs[:len(free)])
+            for r in take:
+                assert len(r.prompt) < self.max_len, \
+                    "prompt exceeds engine max_len"
+                r.admitted = t_take
+            pairs = list(zip(free, take))
+            if not pairs:
+                return 0
+            for group in self._admission_groups(pairs):
+                self._admit_group(group, now)
+            self._recompute_remaining()
+            return len(pairs)
 
     def _admission_groups(self, pairs: List[Tuple[int, "Request"]]
                           ) -> List[List]:
@@ -502,60 +535,74 @@ class ServingEngine:
         else:
             S = max(lens)
         Gp = min(1 << (G - 1).bit_length(), self.slots)
-        toks = np.zeros((Gp, S), np.int32)
-        for i, r in enumerate(reqs):
-            toks[i, :lens[i]] = r.prompt
-        cache_g = M.init_cache(self.cfg, Gp, self.max_len)
-        if self._prefill_custom is not None:
-            logits, cache_g = self._prefill_custom(
-                self.params, cache_g,
-                jnp.asarray(toks[:G, :max(lens)], jnp.int32))
-        else:
-            last = np.zeros(Gp, np.int32)
-            last[:G] = np.asarray(lens) - 1
-            if self._can_chunk(S):
-                # chunked prefill: live decode slots keep streaming
-                # between chunks instead of stalling for the whole
-                # prompt (the colocated head-of-line fix)
-                logits, cache_g = self._prefill_chunks(
-                    cache_g, toks, last, now, interleave=True)
+        chunked = self._prefill_custom is None and self._can_chunk(S)
+        with TraceAnnotation("engine.admit.prepare"):
+            toks = np.zeros((Gp, S), np.int32)
+            for i, r in enumerate(reqs):
+                toks[i, :lens[i]] = r.prompt
+            cache_g = M.init_cache(self.cfg, Gp, self.max_len)
+            if self._prefill_custom is not None:
+                args = (jnp.asarray(toks[:G, :max(lens)], jnp.int32),)
             else:
-                logits, cache_g = self._prefill(
-                    cache_g, jnp.asarray(toks, jnp.int32),
-                    jnp.asarray(last, jnp.int32))
-        self._write_slots(slots_, cache_g, G)
+                last = np.zeros(Gp, np.int32)
+                last[:G] = np.asarray(lens) - 1
+                if not chunked:
+                    args = (jnp.asarray(toks, jnp.int32),
+                            jnp.asarray(last, jnp.int32))
+        if chunked:
+            # chunked prefill: live decode slots keep streaming between
+            # chunks instead of stalling for the whole prompt (the
+            # colocated head-of-line fix)
+            logits, cache_g = self._prefill_chunks(
+                cache_g, toks, last, now, interleave=True)
+        else:
+            with TraceAnnotation("engine.admit.prefill"):
+                if self._prefill_custom is not None:
+                    logits, cache_g = self._prefill_custom(
+                        self.params, cache_g, *args)
+                else:
+                    logits, cache_g = self._prefill(cache_g, *args)
+        self.stats.prefill_tokens += sum(lens)
+        self.stats.prefill_padded_tokens += (
+            G * max(lens) if self._prefill_custom is not None else Gp * S)
+        with TraceAnnotation("engine.admit.write"):
+            self._write_slots(slots_, cache_g, G)
         # honest TTFT: the first token exists only once logits are real
-        jax.block_until_ready(logits)
+        with TraceAnnotation("engine.admit.wait"):
+            jax.block_until_ready(logits)
         t_ready = self._now(now)
-        first = self._sample_host(logits)[:G]
+        with TraceAnnotation("engine.admit.sample"):
+            first = self._sample_host(logits)[:G]
         self.stats.prefill_batches += 1
 
-        upd_slots = jnp.asarray(slots_, jnp.int32)
-        self.pos = self.pos.at[upd_slots].set(
-            jnp.asarray(lens, jnp.int32))
-        self.last_tok = self.last_tok.at[upd_slots].set(
-            jnp.asarray(first, jnp.int32))
-        budgets = [r.max_new_tokens - 1 for r in reqs]
-        self.budget = self.budget.at[upd_slots].set(
-            jnp.asarray(budgets, jnp.int32))
-        # a slot only becomes live if it still has budget AND the
-        # prefill token was not EOS — otherwise the device mask would
-        # keep a ghost slot decoding after the host finalized it
-        live = [b > 0 and not (self.eos_id is not None
-                               and int(t) == self.eos_id)
-                for b, t in zip(budgets, first)]
-        self.active_mask = self.active_mask.at[upd_slots].set(
-            jnp.asarray(live))
+        with TraceAnnotation("engine.admit.slots"):
+            upd_slots = jnp.asarray(slots_, jnp.int32)
+            self.pos = self.pos.at[upd_slots].set(
+                jnp.asarray(lens, jnp.int32))
+            self.last_tok = self.last_tok.at[upd_slots].set(
+                jnp.asarray(first, jnp.int32))
+            budgets = [r.max_new_tokens - 1 for r in reqs]
+            self.budget = self.budget.at[upd_slots].set(
+                jnp.asarray(budgets, jnp.int32))
+            # a slot only becomes live if it still has budget AND the
+            # prefill token was not EOS — otherwise the device mask
+            # would keep a ghost slot decoding after the host finalized
+            # it
+            live = [b > 0 and not (self.eos_id is not None
+                                   and int(t) == self.eos_id)
+                    for b, t in zip(budgets, first)]
+            self.active_mask = self.active_mask.at[upd_slots].set(
+                jnp.asarray(live))
 
-        for i, (slot, req) in enumerate(group):
-            tok = int(first[i])
-            req.ttft = t_ready
-            req.output.append(tok)
-            if live[i]:
-                self.active[slot] = req
-            else:
-                # completes at prefill (budget spent or EOS sampled)
-                self._finalize(req, t_ready)
+            for i, (slot, req) in enumerate(group):
+                tok = int(first[i])
+                req.ttft = t_ready
+                req.output.append(tok)
+                if live[i]:
+                    self.active[slot] = req
+                else:
+                    # completes at prefill (budget spent or EOS sampled)
+                    self._finalize(req, t_ready)
 
     # ------------------------------------------------------------------ #
     # Chunked prefill: incremental cache fill with decode interleaving
@@ -686,18 +733,20 @@ class ServingEngine:
                 self.sync(now)
             if not any(r is not None for r in self.active):
                 return
-        if self._decode_custom is not None:
-            logits, self.cache = self._decode_custom(
-                self.params, self.cache, self.last_tok[:, None], self.pos)
-            (self.last_tok, self.pos, self.budget, self.active_mask,
-             packed, self.key) = self._post(
-                logits, self.last_tok, self.pos, self.budget,
-                self.active_mask, self.key)
-        else:
-            (self.cache, self.last_tok, self.pos, self.budget,
-             self.active_mask, packed, self.key) = self._step_fused(
-                self.cache, self.last_tok, self.pos,
-                self.budget, self.active_mask, self.key)
+        with TraceAnnotation("engine.decode_dispatch"):
+            if self._decode_custom is not None:
+                logits, self.cache = self._decode_custom(
+                    self.params, self.cache, self.last_tok[:, None],
+                    self.pos)
+                (self.last_tok, self.pos, self.budget, self.active_mask,
+                 packed, self.key) = self._post(
+                    logits, self.last_tok, self.pos, self.budget,
+                    self.active_mask, self.key)
+            else:
+                (self.cache, self.last_tok, self.pos, self.budget,
+                 self.active_mask, packed, self.key) = self._step_fused(
+                    self.cache, self.last_tok, self.pos,
+                    self.budget, self.active_mask, self.key)
         self._cols.append(packed)
         self.stats.decode_steps += 1
         if self._paged is not None:
@@ -714,15 +763,23 @@ class ServingEngine:
         On paged engines the settled boundary is also the scheduling
         point: parked sessions rotate into freed slots here."""
         if self._cols:
-            # one stacked D2H fetch for the whole window, not one per
-            # step
+            with TraceAnnotation("engine.sync"):
+                self._settle_window(now)
+        if self._paged is not None:
+            self._schedule(now)
+
+    def _settle_window(self, now: float) -> None:
+        """Fetch the buffered steps' tokens and flags; settle them."""
+        # one stacked D2H fetch for the whole window, not one per step
+        with TraceAnnotation("engine.sync.fetch"):
             cols = self._cols[0] if len(self._cols) == 1 else \
                 jnp.stack(self._cols, axis=2)
             window = np.asarray(cols).reshape(2, self.slots, -1)
-            toks, dones = window[0], window[1]             # (slots, k)
-            self._cols = []
-            self.stats.host_syncs += 1
-            t_set = self._now(now)
+        toks, dones = window[0], window[1]                 # (slots, k)
+        self._cols = []
+        self.stats.host_syncs += 1
+        t_set = self._now(now)
+        with TraceAnnotation("engine.sync.settle"):
             for s in range(self.slots):
                 req = self.active[s]
                 if req is None:
@@ -737,8 +794,6 @@ class ServingEngine:
                         self.active[s] = None
                         break
             self._recompute_remaining()
-        if self._paged is not None:
-            self._schedule(now)
 
     def _recompute_remaining(self) -> None:
         rem = [r.max_new_tokens - len(r.output)
@@ -791,7 +846,8 @@ class ServingEngine:
                         # idle until the next arrival: sleep, don't spin
                         delay = pending[0].arrival - self._clock()
                         if delay > 0:
-                            time.sleep(delay)
+                            with TraceAnnotation("engine.wait"):
+                                time.sleep(delay)
                     continue
                 self.step(self._clock())
             self.sync(self._clock())

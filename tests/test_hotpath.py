@@ -324,6 +324,41 @@ def test_engine_ttft_is_stamped_after_prefill():
         assert r.finished >= r.ttft
 
 
+def test_engine_counts_prefill_padding():
+    """``prefill_tokens`` counts the real prompt tokens admitted,
+    ``prefill_padded_tokens`` the rows x length the prefill computed;
+    they agree for a lone prompt whose length is a multiple of 8."""
+    cfg = _engine_cfg()
+    params = M.init_params(cfg)
+    rng = np.random.default_rng(13)
+
+    def served(lens):
+        eng = ServingEngine(cfg, params, slots=4, max_len=32)
+        eng.run([Request(rid=i, prompt=rng.integers(0, cfg.vocab_size,
+                                                    size=n).astype(np.int32),
+                         max_new_tokens=2) for i, n in enumerate(lens)])
+        return eng.stats.prefill_tokens, eng.stats.prefill_padded_tokens
+
+    assert served([16]) == (16, 16)
+    # three ragged prompts: one batch of 4 rows x 16 positions
+    assert served([5, 9, 16]) == (30, 64)
+
+
+def test_engine_stamps_admission_between_arrival_and_first_token():
+    """``admitted`` falls between the request's arrival and its first
+    token, for requests admitted at once and for ones that queue."""
+    cfg = _engine_cfg()
+    params = M.init_params(cfg)
+    rng = np.random.default_rng(19)
+    reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab_size, size=6)
+                    .astype(np.int32), max_new_tokens=4, arrival=0.01 * i)
+            for i in range(5)]
+    eng = ServingEngine(cfg, params, slots=2, max_len=32, sync_every=2)
+    assert eng.run(reqs).completed == 5
+    for r in reqs:
+        assert r.arrival <= r.admitted <= r.ttft <= r.finished
+
+
 def test_engine_trace_driven():
     """serving.workload traces drive the real engine end to end."""
     from repro.serving.engine import requests_from_trace

@@ -30,6 +30,10 @@ from repro.core.marker import MARKER_NAME
 Var = Any       # jex_core.Var
 Atom = Any      # Var | Literal
 
+# Writes into a buffer: their result is the operand (invars[0]) updated,
+# so where the operand is cross-iteration state the result is too.
+_UPDATE_PRIMS = frozenset({"scatter", "dynamic_update_slice"})
+
 
 # --------------------------------------------------------------------- #
 # Per-primitive FLOP / byte estimation
@@ -206,7 +210,9 @@ def analyze(fn: Callable, *example_args, name: str = "ddg",
     ``state_argnums``: positional args holding cross-iteration state (e.g.
     KV caches); kernels reading them and kernels producing the matching
     outputs are reported in ``state_readers`` / ``state_writers`` so the
-    planner can pin them (DESIGN.md §2, KV pinning).
+    planner can pin them (DESIGN.md §2, KV pinning).  A scatter or
+    dynamic_update_slice into state yields state, so a buffer updated
+    layer after layer keeps every reader and writer along the chain.
     """
     closed, out_shape = jax.make_jaxpr(fn, return_shape=True)(
         *example_args, **example_kwargs)
@@ -276,6 +282,8 @@ def analyze(fn: Callable, *example_args, name: str = "ddg",
                 continue
             if v in state_in_vars:
                 state_readers.add(node_idx)
+                if pname in _UPDATE_PRIMS and v is resolve(eqn.invars[0]):
+                    state_in_vars.add(eqn.outvars[0])
             src = producer.get(v)
             if src is not None and src != node_idx:
                 key = (src, node_idx)

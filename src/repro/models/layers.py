@@ -206,6 +206,7 @@ def attention(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
               causal: bool = True,
               q_chunk: int = 512,
               positions3: Optional[jnp.ndarray] = None,
+              cache_layer=None,
               ) -> Tuple[jnp.ndarray, Optional[Dict[str, jnp.ndarray]]]:
     """GQA attention.
 
@@ -218,7 +219,11 @@ def attention(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
         prefix plus this chunk; unfilled higher slots are excluded by
         the causal mask, so the result equals whole-prompt prefill).
       * kv_cache given + single-token x: decode step, in-place cache
-        update at ``cache_pos`` (ring-buffer position for SWA).
+        update at ``cache_pos`` (ring-buffer position for SWA).  With
+        ``cache_layer`` the cache is the whole stacked (L, B, T, Hkv, D)
+        buffer: the token's K/V land at ``[cache_layer, b, cache_pos]``
+        and the step attends over ``cache[cache_layer]``, so the layer
+        loop can carry the buffer and update it in place.
       * cross_kv given: cross-attention over precomputed encoder K/V.
     Returns (output, updated kv_cache or None).
     """
@@ -254,9 +259,12 @@ def attention(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
     if kv_cache is not None and cross_kv is None:
         if S == 1:
             # decode: write this token's K/V at cache_pos, read whole cache
-            new_cache = {"k": _dyn_update(kv_cache["k"], k, cache_pos),
-                         "v": _dyn_update(kv_cache["v"], v, cache_pos)}
+            new_cache = {
+                "k": _write_token(kv_cache["k"], k, cache_pos, cache_layer),
+                "v": _write_token(kv_cache["v"], v, cache_pos, cache_layer)}
             k, v = new_cache["k"], new_cache["v"]
+            if cache_layer is not None:
+                k, v = k[cache_layer], v[cache_layer]
             cap = k.shape[1]
             if window is not None and cap <= window:
                 # Ring buffer: every written slot is within the window;
@@ -304,18 +312,20 @@ def attention(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
     return y, new_cache
 
 
-def _dyn_update(cache, val, pos):
-    """cache (B, T, H, D) <- val (B, 1, H, D) at per-batch positions.
+def _write_token(cache, val, pos, layer=None):
+    """cache (B, T, H, D) <- val (B, 1, H, D) at per-batch positions, as
+    one scatter of B rows; with ``layer`` the cache is (L, B, T, H, D)
+    and the rows land in that layer.
 
     ``pos`` may be a python int, a scalar array, or a (B,) vector (each
-    request in a continuous batch sits at its own position)."""
-    val = val.astype(cache.dtype)
-    pos = jnp.asarray(pos)
-    if pos.ndim == 0:
-        return jax.lax.dynamic_update_slice_in_dim(cache, val, pos, axis=1)
-    return jax.vmap(
-        lambda c, u, s: jax.lax.dynamic_update_slice_in_dim(
-            c, u, s, axis=0))(cache, val, pos)
+    request in a continuous batch sits at its own position).  Positions
+    clamp to the capacity, as ``dynamic_update_slice`` clamps them."""
+    B = val.shape[0]
+    rows = (jnp.arange(B), jnp.broadcast_to(jnp.asarray(pos, jnp.int32),
+                                            (B,)))
+    if layer is not None:
+        rows = (layer,) + rows
+    return cache.at[rows].set(val[:, 0].astype(cache.dtype), mode="clip")
 
 
 def _fill(cache, val):

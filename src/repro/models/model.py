@@ -22,6 +22,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import marker
 from repro.models import layers as L
@@ -108,7 +109,8 @@ def init_params(cfg: ModelConfig, key=None) -> Params:
 # Layer bodies (shared by scan and unrolled paths)
 # ===================================================================== #
 def _dense_block(lp, x, cfg, *, positions, cache=None, cache_pos=None,
-                 positions3=None, layer_idx=-1, tagged=False):
+                 cache_layer=None, positions3=None, layer_idx=-1,
+                 tagged=False):
     # Region markers open BEFORE the computation so the analyzer tags
     # every kernel traced inside the block (begin ... end brackets the
     # equation stream).
@@ -117,7 +119,7 @@ def _dense_block(lp, x, cfg, *, positions, cache=None, cache_pos=None,
         xin, close = marker.tag(xin, block="attention", layer=layer_idx)
     h, new_cache = L.attention(
         lp["attn"], xin, cfg, positions=positions, kv_cache=cache,
-        cache_pos=cache_pos, positions3=positions3)
+        cache_pos=cache_pos, positions3=positions3, cache_layer=cache_layer)
     if tagged:
         h = close(h)
     x = x + h
@@ -810,17 +812,20 @@ def decode_step(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         cap = cache["kv"]["k"].shape[2]
         slot = pos % cap if cfg.sliding_window is not None else pos
 
-        def body(x, xs):
-            lp, ck, cv = xs
-            y, nc = _dense_block(lp, x, cfg, positions=positions,
-                                 cache={"k": ck, "v": cv},
-                                 cache_pos=slot, positions3=positions3,
-                                 tagged=not scan_layers)
-            return y, (nc["k"], nc["v"])
-        x, (nk, nv) = _run_stack(
-            body, x, (params["layers"], cache["kv"]["k"],
-                      cache["kv"]["v"]), scan_layers)
-        new_cache["kv"] = {"k": nk, "v": nv}
+        # The stacked cache rides in the carry: each layer scatters its
+        # B new rows at [layer, b, slot] and reads its own slice, so a
+        # donated cache is updated in place instead of rebuilt as the
+        # scan's output.  The layer index is a numpy int when unrolled.
+        def body(carry, xs):
+            x, kv = carry
+            lp, i = xs
+            return _dense_block(lp, x, cfg, positions=positions, cache=kv,
+                                cache_pos=slot, cache_layer=i,
+                                positions3=positions3,
+                                tagged=not scan_layers), None
+        (x, new_cache["kv"]), _ = _run_stack(
+            body, (x, cache["kv"]),
+            (params["layers"], np.arange(cfg.num_layers)), scan_layers)
 
     elif cfg.family == "ssm":
         st = cache["rwkv"]

@@ -164,8 +164,10 @@ def _post(logits, last_tok, pos, budget, active, key, *, eos: int,
 post_step = jax.jit(_post, static_argnames=("eos", "temp", "max_len"))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "eos", "temp", "max_len"))
+_DECODE_STATIC = ("cfg", "eos", "temp", "max_len")
+
+
+@functools.partial(jax.jit, static_argnames=_DECODE_STATIC)
 def decode_fused(params, cache, last_tok, pos, budget, active, key, *,
                  cfg: ModelConfig, eos: int, temp: float, max_len: int):
     """One decode step over every slot, with sampling and termination."""
@@ -173,6 +175,22 @@ def decode_fused(params, cache, last_tok, pos, budget, active, key, *,
                                   pos)
     return (cache,) + _post(logits, last_tok, pos, budget, active, key,
                             eos=eos, temp=temp, max_len=max_len)
+
+
+@functools.cache
+def donating(program: Callable) -> Callable:
+    """``program`` (a decode program of ``decode_fused``'s signature)
+    compiled with its cache donated, under the program's own name.
+
+    The engine owns its cache, so it runs the decode step this way: the
+    step writes each slot's new K/V rows into the buffer it was given
+    (``M.decode_step`` carries the cache through the layer loop) instead
+    of producing a second whole cache, and the array passed in is
+    deleted.  ``decode_fused`` itself stays pure, so a caller that keeps
+    its arrays can call or lower it, and a program substituted for it
+    runs inside the donating one and may return its own input."""
+    return jax.jit(program, static_argnames=_DECODE_STATIC,
+                   donate_argnames=("cache",))
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -262,8 +280,9 @@ class ServingEngine:
                          **self._sampling)
 
     def _step_fused(self, cache, last_tok, pos, budget, active, key):
-        return decode_fused(self.params, cache, last_tok, pos, budget,
-                            active, key, cfg=self.cfg, **self._sampling)
+        return donating(decode_fused)(self.params, cache, last_tok, pos,
+                                      budget, active, key, cfg=self.cfg,
+                                      **self._sampling)
 
     def _prefill(self, cache, toks, last_pos):
         return prefill_batch(self.params, cache, toks, last_pos,
@@ -702,9 +721,10 @@ class ServingEngine:
         """Prime the jitted prefill and fused decode step (the common
         shape buckets) so a freshly scaled-in engine pays its compiles
         BEFORE it is marked routable, not on the first real request.
-        Outputs are discarded; engine state is untouched (the decode
-        probe runs fully masked, and the position-0 rows it touches
-        are overwritten by any admission or import)."""
+        Outputs are discarded except the cache, which the decode probe
+        donates; engine state is otherwise untouched (the probe runs
+        fully masked, and the rows it writes at each slot's position
+        are overwritten by any admission, import or real step)."""
         if self._prefill_custom is None:
             cache1 = M.init_cache(self.cfg, 1, self.max_len)
             logits, _ = self._prefill(
@@ -715,6 +735,7 @@ class ServingEngine:
             out = self._step_fused(self.cache, self.last_tok, self.pos,
                                    self.budget, self.active_mask,
                                    self.key)
+            self.cache = out[0]
             jax.block_until_ready(out[1])
 
     # ------------------------------------------------------------------ #

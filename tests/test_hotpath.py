@@ -390,14 +390,14 @@ def test_engine_weights_enter_as_arguments(step):
     closed-over weight would be baked into every executable (one copy per
     prefill bucket on the device)."""
     import re
-    from repro.serving.engine import (decode_fused, prefill_batch,
-                                      prefill_chunk)
+    from repro.serving.engine import (decode_fused, donating,
+                                      prefill_batch, prefill_chunk)
     cfg = configs.get_smoke("qwen3_1_7b")
     params = M.init_params(cfg)
     cache = M.init_cache(cfg, 2, 32)
     if step == "decode":
         z = jnp.zeros(2, jnp.int32)
-        lowered = decode_fused.lower(
+        lowered = donating(decode_fused).lower(
             params, cache, z, z, z, jnp.ones(2, bool),
             jax.random.PRNGKey(0), cfg=cfg, eos=-1, temp=0.0, max_len=32)
     elif step == "prefill":

@@ -7,6 +7,10 @@ do not fit the chip's memory.  These tests compile, at real widths:
   * the serving engine's decode step and one prefill bucket for
     full-width qwen3-1.7B, from ``jax.eval_shape`` shapes, and check
     that arguments + outputs + temporaries fit one chip's 16 GiB;
+  * the decode step at the benchmark cell's 16 slots x 1024, and check
+    that it updates its donated KV cache in place: the output aliases
+    the cache, no temporary near its size, and no copy or
+    dynamic-update-slice writes a whole new cache;
   * each of the five Pallas kernel entry points, and check that the
     kernel is in the program (``tpu_custom_call``).
 
@@ -14,6 +18,8 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
 imports this file.  Keep these compiles in this one file.
 """
+import re
+
 import pytest
 
 import jax
@@ -27,7 +33,7 @@ from repro.kernels.mamba2_ssd.kernel import ssd_chunk_scan
 from repro.kernels.moe_gmm.kernel import gmm
 from repro.kernels.rwkv6.kernel import wkv
 from repro.models import model as M
-from repro.serving.engine import decode_fused, prefill_batch
+from repro.serving.engine import decode_fused, donating, prefill_batch
 
 HBM_BYTES = 16 * 2**30                  # one v5e chip
 SLOTS, MAX_LEN, PROMPT = 8, 2048, 1024  # chip_smoke.py's engine
@@ -89,7 +95,7 @@ def test_engine_step_compiles_for_v5e(one_chip, step):
         lambda: M.init_cache(cfg, SLOTS, MAX_LEN)))
     if step == "decode":
         i32 = S((SLOTS,), jnp.int32)
-        lowered = decode_fused.lower(
+        lowered = donating(decode_fused).lower(
             params, cache, i32, i32, i32, S((SLOTS,), jnp.bool_),
             S((2,), jnp.uint32), cfg=cfg, eos=-1, temp=0.0,
             max_len=MAX_LEN)
@@ -98,6 +104,38 @@ def test_engine_step_compiles_for_v5e(one_chip, step):
             params, cache, S((SLOTS, PROMPT), jnp.int32),
             S((SLOTS,), jnp.int32), cfg=cfg)
     _fits_one_chip(lowered.compile(), params)
+
+
+def test_decode_updates_cache_in_place_for_v5e(one_chip):
+    cfg = configs.get("qwen3_1_7b")
+    slots, max_len = 16, 1024              # the qwen3-chat cell's engine
+    params = _on(one_chip, jax.eval_shape(lambda: M.init_params(cfg)))
+    cache = _on(one_chip, jax.eval_shape(
+        lambda: M.init_cache(cfg, slots, max_len)))
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32 = S((slots,), jnp.int32)
+    compiled = donating(decode_fused).lower(
+        params, cache, i32, i32, i32, S((slots,), jnp.bool_),
+        S((2,), jnp.uint32), cfg=cfg, eos=-1, temp=0.0,
+        max_len=max_len).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(l.size * l.dtype.itemsize
+                      for l in jax.tree_util.tree_leaves(cache))
+    assert mem.alias_size_in_bytes >= cache_bytes, mem
+    assert mem.temp_size_in_bytes < 64 * 2**20, mem
+    whole = "bf16[{}]".format(",".join(
+        map(str, cache["kv"]["k"].shape)))
+    rebuilt = []
+    for m in re.finditer(r"%(\S+) = (\S+)\{[^}]*\} ([\w-]+)\(",
+                         compiled.as_text()):
+        name, shape, op = m.groups()
+        if shape == whole and (op in ("copy", "dynamic-update-slice")
+                               or "dynamic-update-slice" in name):
+            rebuilt.append(name)
+    assert not rebuilt, rebuilt
 
 
 def _kernel_cases(S):

@@ -10,6 +10,7 @@ CONFIG = ModelConfig(
     name="qwen2.5-14b", family="dense",
     num_layers=48, d_model=5120, num_heads=40, num_kv_heads=8,
     d_ff=13824, vocab_size=152064, qkv_bias=True, rope_theta=1e6,
+    norm_eps=1e-5,
 )
 
 SMOKE = dataclasses.replace(

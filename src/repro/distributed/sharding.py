@@ -154,6 +154,13 @@ def _mesh_axes_for(mesh: Mesh, rule) -> Tuple[Tuple[str, ...], int]:
     return axes, size
 
 
+def head_shards(mesh: Mesh, rules: Dict[str, Any]) -> int:
+    """Ways ``rules`` split query heads over ``mesh``; 1 where no axis of
+    the mesh does.  A model's ``head_shards`` for that mesh: its query
+    heads pad to a multiple of it where it does not divide them."""
+    return _mesh_axes_for(mesh, rules.get("heads"))[1]
+
+
 def spec_for(shape: Sequence[int], logical: LogicalAxes, mesh: Mesh,
              rules: Dict[str, Any]) -> P:
     """PartitionSpec with divisibility fallback to replication.

@@ -36,6 +36,7 @@ from pathlib import Path
 
 import jax
 
+from repro.distributed import sharding as SH
 from repro.launch import mesh as mesh_lib
 from repro.launch import steps as steps_lib
 from repro.models.config import SHAPES
@@ -50,10 +51,9 @@ DEFAULT_OUT = REPO / "experiments" / "dryrun"
 def build_shardings(cell, specs, mesh):
     """(in_shardings, out_shardings) trees for the cell's step."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.distributed import sharding as SH
     from repro.train import optim
 
-    rules = SH.TRAIN_RULES if cell.step_kind == "train" else SH.SERVE_RULES
+    rules = _rules(cell.step_kind)
     rep = NamedSharding(mesh, P())
 
     def batch_shardings(bspecs):
@@ -91,10 +91,17 @@ def build_shardings(cell, specs, mesh):
     return in_sh, out_sh
 
 
-def production_cfg(cfg):
+def _rules(step_kind):
+    return SH.TRAIN_RULES if step_kind == "train" else SH.SERVE_RULES
+
+
+def production_cfg(cfg, mesh, step_kind):
     """Per-mesh model-impl switches (hillclimb, EXPERIMENTS.md §Perf):
-    MoE uses the expert-local shard_map path on production meshes — the
-    sort-based ragged path forces GSPMD to globalize every token."""
+    query heads pad to the ways the mesh shards them; MoE uses the
+    expert-local shard_map path on production meshes — the sort-based
+    ragged path forces GSPMD to globalize every token."""
+    cfg = dataclasses.replace(
+        cfg, head_shards=SH.head_shards(mesh, _rules(step_kind)))
     if cfg.family == "moe":
         return dataclasses.replace(cfg, moe_impl="ep")
     return cfg
@@ -103,7 +110,8 @@ def production_cfg(cfg):
 def compile_cell(cell, mesh, remat=True):
     """Returns (compiled, lowered, stats dict)."""
     from repro.distributed.context import mesh_context
-    cell = dataclasses.replace(cell, cfg=production_cfg(cell.cfg))
+    cell = dataclasses.replace(
+        cell, cfg=production_cfg(cell.cfg, mesh, cell.step_kind))
     step = steps_lib.make_step(cell, remat=remat)
     specs = steps_lib.input_specs(cell)
     in_sh, out_sh = build_shardings(cell, specs, mesh)
@@ -170,7 +178,8 @@ def extrapolate_roofline(cell, mesh, remat=True):
 
     def one(units):
         from repro.distributed.context import mesh_context
-        cfg_u = production_cfg(_unrolled_cfg(cell.cfg, units))
+        cfg_u = production_cfg(_unrolled_cfg(cell.cfg, units), mesh,
+                               cell.step_kind)
         cell_u = dataclasses.replace(cell, cfg=cfg_u)
         step = _make_unrolled_step(cell_u, remat)
         specs = S.input_specs(cell_u)

@@ -32,7 +32,8 @@ def main():
     mesh = mesh_lib.make_production_mesh(multi_pod=args.mesh == "pod2")
     cell = steps_lib.get_cell(args.arch, args.shape)
     if args.units:
-        cfg_u = production_cfg(_unrolled_cfg(cell.cfg, args.units))
+        cfg_u = production_cfg(_unrolled_cfg(cell.cfg, args.units), mesh,
+                               cell.step_kind)
         cell = dataclasses.replace(cell, cfg=cfg_u)
         step = _make_unrolled_step(cell, remat=True)
         specs = steps_lib.input_specs(cell)

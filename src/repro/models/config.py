@@ -7,6 +7,7 @@ configurations and reduced smoke variants.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Tuple
 
 import jax.numpy as jnp
@@ -63,6 +64,14 @@ class ModelConfig:
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
 
+    # ways a mesh axis splits the query heads.  A config is laid out for
+    # the production mesh's 16-way ``model`` axis until the code that
+    # places it says otherwise: ``launch.dryrun.production_cfg`` sets it
+    # from its mesh, ``DeploymentSpec.launch`` and the one-host
+    # ``train.loop.Trainer`` to 1 (they hold whole layers, so one chip or
+    # a pipeline stage holds every head)
+    head_shards: int = 16
+
     # -------------------------------------------------------------- #
     def __post_init__(self):
         if self.head_dim == 0 and self.num_heads:
@@ -75,16 +84,19 @@ class ModelConfig:
 
     @property
     def padded_heads(self) -> int:
-        """Query heads padded to a 16 multiple (the production mesh's
-        model-axis size) so attention shards instead of replicating —
-        without this, qwen2.5's 40 heads replicate 16-way (16x compute
-        and activation memory).  Extra heads are zero-initialized
-        (Megatron-style head padding); the forward output is EXACT
-        because the padded wo rows are zero."""
-        H = self.num_heads
-        if H == 0 or H % 16 == 0:
+        """Query heads as the parameters hold them: the published count,
+        unless a mesh axis of ``head_shards`` ways splits heads and does
+        not divide it.  Then they pad up to a multiple of that axis and
+        of the K/V heads (Megatron-style), so attention shards instead
+        of replicating and every K/V group keeps a run of
+        ``padded_heads // num_kv_heads`` slots, its real heads first
+        (``layers.head_slots``).  Padded heads have zero q/o slices, so
+        the output is EXACT."""
+        H, n = self.num_heads, self.head_shards
+        if H == 0 or H % n == 0:
             return H
-        return H + (-H % 16)
+        m = math.lcm(n, self.num_kv_heads)
+        return H + (-H % m)
 
     @property
     def padded_vocab(self) -> int:

@@ -14,6 +14,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.config import ModelConfig
 
@@ -81,6 +82,16 @@ def apply_mrope(x: jnp.ndarray, positions3: jnp.ndarray,
 # --------------------------------------------------------------------- #
 # Attention (GQA / MQA / SWA / cross) with query chunking
 # --------------------------------------------------------------------- #
+def head_slots(cfg: ModelConfig, padded: int) -> np.ndarray:
+    """Where each published query head sits among ``padded`` slots.
+    Slot h reads K/V head h // (padded / K/V heads), so head j, of group
+    j // (heads / K/V heads), takes the same place in its group's run
+    of slots: each group's real heads first."""
+    rep = cfg.num_heads // cfg.num_kv_heads
+    j = np.arange(cfg.num_heads)
+    return (j // rep) * (padded // cfg.num_kv_heads) + j % rep
+
+
 def init_attention(cfg: ModelConfig, d_model: Optional[int] = None,
                    key=None) -> Params:
     d = d_model or cfg.d_model
@@ -95,7 +106,8 @@ def init_attention(cfg: ModelConfig, d_model: Optional[int] = None,
     if H != cfg.num_heads:
         # Megatron-style head padding: zero q/o slices for TP
         # divisibility — output is exactly the unpadded model's.
-        mask = (jnp.arange(H) < cfg.num_heads)
+        mask = np.zeros(H, bool)
+        mask[head_slots(cfg, H)] = True
         wq = wq * mask[None, :, None]
         wo = wo * mask[:, None, None]
     p = {

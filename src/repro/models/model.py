@@ -105,6 +105,24 @@ def init_params(cfg: ModelConfig, key=None) -> Params:
     return p
 
 
+# query-head axis of an attention block's leaves that have one
+_HEAD_AXIS = {"wq": -2, "bq": -2, "wo": -3}
+
+
+def published_heads(params: Params, cfg: ModelConfig) -> Params:
+    """``params`` with every attention block holding the ``num_heads``
+    published query heads, wherever a mesh's padding laid them out wider
+    (their slots: ``layers.head_slots``).  Other leaves pass through."""
+    def cut(path, x):
+        keys = [getattr(k, "key", None) for k in path[-2:]]
+        axis = _HEAD_AXIS.get(keys[-1])
+        if keys[0] not in ("attn", "xattn") or axis is None \
+                or x.shape[axis] == cfg.num_heads:
+            return x
+        return jnp.take(x, L.head_slots(cfg, x.shape[axis]), axis=axis)
+    return jax.tree_util.tree_map_with_path(cut, params)
+
+
 # ===================================================================== #
 # Layer bodies (shared by scan and unrolled paths)
 # ===================================================================== #

@@ -669,9 +669,13 @@ class Deployment:
             cfg = (configs.get_smoke(spec.arch)
                    if spec.engine.get("smoke", True)
                    else configs.get(spec.arch))
-        if params is None:
-            from repro.models import model as M
-            params = M.init_params(cfg)
+        from repro.models import model as M
+        # each engine holds whole layers on its chip: no mesh axis splits
+        # query heads, so they are the published ones, padded or not as
+        # ``params`` come
+        cfg = dataclasses.replace(cfg, head_shards=1)
+        params = (M.init_params(cfg) if params is None
+                  else M.published_heads(params, cfg))
         return LaunchedDeployment(spec, cfg, params)
 
 

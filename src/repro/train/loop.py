@@ -40,7 +40,9 @@ class TrainConfig:
 class Trainer:
     def __init__(self, cfg: ModelConfig, tcfg: TrainConfig,
                  ocfg: Optional[optim.AdamWConfig] = None):
-        self.cfg = cfg
+        # one host: no mesh axis splits query heads, so it trains the
+        # published ones
+        self.cfg = dataclasses.replace(cfg, head_shards=1)
         self.tcfg = tcfg
         self.ocfg = ocfg or optim.AdamWConfig(
             warmup_steps=max(tcfg.steps // 10, 1),

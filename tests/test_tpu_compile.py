@@ -11,6 +11,9 @@ do not fit the chip's memory.  These tests compile, at real widths:
     that it updates its donated KV cache in place: the output aliases
     the cache, no temporary near its size, and no copy or
     dynamic-update-slice writes a whole new cache;
+  * the decode step and a 16 x 512 prefill of the benchmark's
+    Qwen2.5-14B pipeline stage at its cell's 16 slots x 1024, and check
+    that the arguments hold its published 40 query heads and fit;
   * each of the five Pallas kernel entry points, and check that the
     kernel is in the program (``tpu_custom_call``).
 
@@ -18,7 +21,10 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
 imports this file.  Keep these compiles in this one file.
 """
+import dataclasses
+import json
 import re
+from pathlib import Path
 
 import pytest
 
@@ -27,6 +33,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 import repro.configs as configs
+from chipbench import costs, harness
 from repro.kernels.flash_attention.kernel import (
     flash_attention_decode, flash_attention_prefill)
 from repro.kernels.mamba2_ssd.kernel import ssd_chunk_scan
@@ -136,6 +143,41 @@ def test_decode_updates_cache_in_place_for_v5e(one_chip):
                                or "dynamic-update-slice" in name):
             rebuilt.append(name)
     assert not rebuilt, rebuilt
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_qwen25_stage_holds_published_heads_for_v5e(one_chip, step):
+    conf = json.loads((Path(__file__).resolve().parents[1] / "chipbench"
+                       / "configs" / "qwen2_5_14b-12l.json").read_text())
+    # as ``DeploymentSpec.launch`` holds it: no mesh axis splits heads
+    cfg = dataclasses.replace(harness.program_config(conf), head_shards=1)
+    slots, max_len = 16, 1024              # the qwen25-14b-chat cell
+    params = _on(one_chip, jax.eval_shape(lambda: M.init_params(cfg)))
+    cache = _on(one_chip, jax.eval_shape(
+        lambda: M.init_cache(cfg, slots, max_len)))
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if step == "decode":
+        i32 = S((slots,), jnp.int32)
+        lowered = donating(decode_fused).lower(
+            params, cache, i32, i32, i32, S((slots,), jnp.bool_),
+            S((2,), jnp.uint32), cfg=cfg, eos=-1, temp=0.0,
+            max_len=max_len)
+    else:
+        lowered = prefill_batch.lower(
+            params, cache, S((slots, 512), jnp.int32),
+            S((slots,), jnp.int32), cfg=cfg)
+    compiled = lowered.compile()
+    _fits_one_chip(compiled, params)
+    # the published weights exactly: 9.72 GB (48 padded heads: 9.97 GB)
+    m = costs.Dense.from_config(conf)
+    published = m.weight_bytes + m.vocab * m.d * m.dtype_bytes
+    cache_bytes = sum(l.size * l.dtype.itemsize
+                      for l in jax.tree_util.tree_leaves(cache))
+    args = compiled.memory_analysis().argument_size_in_bytes
+    assert 0 <= args - published - cache_bytes < 2**20, args
 
 
 def _kernel_cases(S):

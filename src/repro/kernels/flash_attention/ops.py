@@ -29,9 +29,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
 
 @functools.partial(jax.jit, static_argnames=(
     "sm_scale", "block_k", "interpret"))
-def flash_decode(q, k, v, lengths, *, sm_scale: Optional[float] = None,
-                 block_k: int = 128, interpret: bool = False):
-    """Decode attention.  q: (B, H, D); k/v: (B, Hkv, T, D); lengths (B,)."""
+def flash_decode(q, k, v, lengths, layer=0, *,
+                 sm_scale: Optional[float] = None, block_k: int = 256,
+                 interpret: bool = False):
+    """Decode attention over live rows.  q: (B, H, D); k/v: the stacked
+    cache (L, B, T, Hkv, D); lengths (B,); layer: the layer read."""
     return K.flash_attention_decode(
-        q, k, v, lengths, sm_scale=sm_scale, block_k=block_k,
+        q, k, v, lengths, layer, sm_scale=sm_scale, block_k=block_k,
         interpret=interpret)

@@ -1,4 +1,5 @@
-"""Pure-jnp oracle for flash attention (prefill and decode)."""
+"""Pure-jnp oracle for flash attention prefill.  The decode kernel's
+oracle is the path it replaces, ``models.layers._sdpa_chunked``."""
 from __future__ import annotations
 
 import math
@@ -33,20 +34,3 @@ def attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     o = jnp.einsum("bhrqk,bhkd->bhrqd", w, v.astype(jnp.float32))
     return o.reshape(B, H, Sq, D).astype(q.dtype)
 
-
-def decode_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-               lengths: jnp.ndarray, *,
-               sm_scale: Optional[float] = None) -> jnp.ndarray:
-    """Single-token decode.  q: (B, H, D); k/v: (B, Hkv, T, D);
-    lengths: (B,) valid KV length per request."""
-    B, H, D = q.shape
-    Hkv, T = k.shape[1], k.shape[2]
-    rep = H // Hkv
-    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
-    qg = q.reshape(B, Hkv, rep, D).astype(jnp.float32) * scale
-    logits = jnp.einsum("bhrd,bhkd->bhrk", qg, k.astype(jnp.float32))
-    valid = jnp.arange(T)[None, :] < lengths[:, None]        # (B, T)
-    logits = jnp.where(valid[:, None, None], logits, -1e30)
-    w = jax.nn.softmax(logits, axis=-1)
-    o = jnp.einsum("bhrk,bhkd->bhrd", w, v.astype(jnp.float32))
-    return o.reshape(B, H, D).astype(q.dtype)

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.flash_attention.ops import flash_decode
 from repro.models.config import ModelConfig
 
 Params = Dict[str, Any]
@@ -219,6 +220,7 @@ def attention(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
               q_chunk: int = 512,
               positions3: Optional[jnp.ndarray] = None,
               cache_layer=None,
+              kv_lengths: Optional[jnp.ndarray] = None,
               ) -> Tuple[jnp.ndarray, Optional[Dict[str, jnp.ndarray]]]:
     """GQA attention.
 
@@ -235,7 +237,10 @@ def attention(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
         ``cache_layer`` the cache is the whole stacked (L, B, T, Hkv, D)
         buffer: the token's K/V land at ``[cache_layer, b, cache_pos]``
         and the step attends over ``cache[cache_layer]``, so the layer
-        loop can carry the buffer and update it in place.
+        loop can carry the buffer and update it in place.  With
+        ``kv_lengths`` too, (B,) live rows per slot, a program lowered
+        for a TPU reads only those rows, in place, through the Pallas
+        decode kernel (``_live_rows_apply``).
       * cross_kv given: cross-attention over precomputed encoder K/V.
     Returns (output, updated kv_cache or None).
     """
@@ -275,6 +280,22 @@ def attention(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
                 "k": _write_token(kv_cache["k"], k, cache_pos, cache_layer),
                 "v": _write_token(kv_cache["v"], v, cache_pos, cache_layer)}
             k, v = new_cache["k"], new_cache["v"]
+            if kv_lengths is not None and _live_rows_apply(cfg,
+                                                           cache_layer):
+                def whole(q, k, v, q_offset, lengths):
+                    return _sdpa_chunked(
+                        q, k[cache_layer], v[cache_layer], causal=True,
+                        q_offset=q_offset, window=None, chunk=q_chunk)
+
+                def live_rows(q, k, v, q_offset, lengths):
+                    return flash_decode(q[:, 0], k, v, lengths, cache_layer,
+                                        block_k=DECODE_BLOCK_K)[:, None]
+
+                out = jax.lax.platform_dependent(
+                    q, k, v, positions[:, 0], kv_lengths, tpu=live_rows,
+                    default=whole)
+                y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+                return y, new_cache
             if cache_layer is not None:
                 k, v = k[cache_layer], v[cache_layer]
             cap = k.shape[1]
@@ -322,6 +343,22 @@ def attention(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
                         softcap=cfg.attn_logit_softcap)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return y, new_cache
+
+
+# K/V rows the decode kernel fetches per tile (PERF.md, section 6)
+DECODE_BLOCK_K = 256
+
+
+def _live_rows_apply(cfg: ModelConfig, cache_layer) -> bool:
+    """Whether decode may read the stacked cache through the Pallas
+    kernel: a layer of the stacked cache, no sliding window (ring
+    buffer), no logit softcap, and one device (GSPMD cannot partition
+    the kernel across a mesh)."""
+    from repro.distributed.context import current_mesh
+    mesh = current_mesh()
+    return (cache_layer is not None and cfg.sliding_window is None
+            and cfg.attn_logit_softcap == 0.0
+            and (mesh is None or mesh.size == 1))
 
 
 def _write_token(cache, val, pos, layer=None):

@@ -127,8 +127,8 @@ def published_heads(params: Params, cfg: ModelConfig) -> Params:
 # Layer bodies (shared by scan and unrolled paths)
 # ===================================================================== #
 def _dense_block(lp, x, cfg, *, positions, cache=None, cache_pos=None,
-                 cache_layer=None, positions3=None, layer_idx=-1,
-                 tagged=False):
+                 cache_layer=None, kv_lengths=None, positions3=None,
+                 layer_idx=-1, tagged=False):
     # Region markers open BEFORE the computation so the analyzer tags
     # every kernel traced inside the block (begin ... end brackets the
     # equation stream).
@@ -137,7 +137,8 @@ def _dense_block(lp, x, cfg, *, positions, cache=None, cache_pos=None,
         xin, close = marker.tag(xin, block="attention", layer=layer_idx)
     h, new_cache = L.attention(
         lp["attn"], xin, cfg, positions=positions, kv_cache=cache,
-        cache_pos=cache_pos, positions3=positions3, cache_layer=cache_layer)
+        cache_pos=cache_pos, positions3=positions3, cache_layer=cache_layer,
+        kv_lengths=kv_lengths)
     if tagged:
         h = close(h)
     x = x + h
@@ -818,9 +819,13 @@ def gather_kv_blocks(pool: Params, block_ids, length: int) -> Params:
 
 def decode_step(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                 cache: Params, pos: jnp.ndarray, *, positions3=None,
-                scan_layers: bool = True) -> Tuple[jnp.ndarray, Params]:
+                scan_layers: bool = True,
+                lengths: Optional[jnp.ndarray] = None
+                ) -> Tuple[jnp.ndarray, Params]:
     """One decode step.  tokens: (B, 1) int32; pos: (B,) absolute
-    positions.  Returns (logits (B, V), updated cache)."""
+    positions; lengths: (B,) K/V rows each slot attends, 0 for an idle
+    slot (default ``pos + 1``).  Returns (logits (B, V), updated
+    cache)."""
     B = tokens.shape[0]
     x = L.embed(params["embed"], tokens).astype(cfg.jnp_dtype)
     positions = pos[:, None]                          # (B, 1)
@@ -834,12 +839,19 @@ def decode_step(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         # B new rows at [layer, b, slot] and reads its own slice, so a
         # donated cache is updated in place instead of rebuilt as the
         # scan's output.  The layer index is a numpy int when unrolled.
+        # Only the scanned loop passes the live lengths on: the unrolled
+        # one is the analyzer's trace, whose cost model would count both
+        # of attention's platform branches.
+        live = None
+        if scan_layers:
+            live = pos + 1 if lengths is None else lengths
+
         def body(carry, xs):
             x, kv = carry
             lp, i = xs
             return _dense_block(lp, x, cfg, positions=positions, cache=kv,
                                 cache_pos=slot, cache_layer=i,
-                                positions3=positions3,
+                                kv_lengths=live, positions3=positions3,
                                 tagged=not scan_layers), None
         (x, new_cache["kv"]), _ = _run_stack(
             body, (x, cache["kv"]),

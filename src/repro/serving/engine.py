@@ -106,6 +106,10 @@ class EngineStats:
     * ``prefill_tokens``: real prompt tokens admitted through them.
     * ``prefill_padded_tokens``: tokens the prefill programs computed
       (rows x length of the padded batch, pads included).
+    * ``decode_kv_rows``: K/V rows the decode steps attended, summed
+      over steps and live slots (a slot at position p attends p + 1).
+    * ``decode_kv_capacity_rows``: steps x slots x ``max_len``, the rows
+      a step that read every slot's whole cache would read.
     """
     completed: int = 0
     decode_steps: int = 0
@@ -113,6 +117,8 @@ class EngineStats:
     prefill_batches: int = 0
     prefill_tokens: int = 0
     prefill_padded_tokens: int = 0
+    decode_kv_rows: int = 0
+    decode_kv_capacity_rows: int = 0
     ttft: List[float] = dataclasses.field(default_factory=list)
     tpot: List[float] = dataclasses.field(default_factory=list)
     latency_per_token: List[float] = dataclasses.field(
@@ -124,6 +130,8 @@ class EngineStats:
             "decode_steps": self.decode_steps,
             "host_syncs": self.host_syncs,
             "prefill_batches": self.prefill_batches,
+            "decode_kv_rows": self.decode_kv_rows,
+            "decode_kv_capacity_rows": self.decode_kv_capacity_rows,
             "mean_ttft": float(np.mean(self.ttft)) if self.ttft else 0.0,
             "mean_tpot": float(np.mean(self.tpot)) if self.tpot else 0.0,
             "mean_norm_latency": float(np.mean(self.latency_per_token))
@@ -172,7 +180,7 @@ def decode_fused(params, cache, last_tok, pos, budget, active, key, *,
                  cfg: ModelConfig, eos: int, temp: float, max_len: int):
     """One decode step over every slot, with sampling and termination."""
     logits, cache = M.decode_step(params, cfg, last_tok[:, None], cache,
-                                  pos)
+                                  pos, lengths=jnp.where(active, pos + 1, 0))
     return (cache,) + _post(logits, last_tok, pos, budget, active, key,
                             eos=eos, temp=temp, max_len=max_len)
 
@@ -799,6 +807,8 @@ class ServingEngine:
         toks, dones = window[0], window[1]                 # (slots, k)
         self._cols = []
         self.stats.host_syncs += 1
+        self.stats.decode_kv_capacity_rows += \
+            toks.shape[1] * self.slots * self.max_len
         t_set = self._now(now)
         with TraceAnnotation("engine.sync.settle"):
             for s in range(self.slots):
@@ -809,6 +819,9 @@ class ServingEngine:
                     t = int(toks[s, k])
                     if t < 0:       # slot went idle earlier in window
                         break
+                    # the step ran at position prompt + output - 1
+                    self.stats.decode_kv_rows += \
+                        len(req.prompt) + len(req.output)
                     req.output.append(t)
                     if dones[s, k]:
                         self._finalize(req, t_set)

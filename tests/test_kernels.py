@@ -20,13 +20,14 @@ except ImportError:
 
 from repro.kernels.flash_attention.kernel import (
     flash_attention_decode, flash_attention_prefill)
-from repro.kernels.flash_attention.ref import attention_ref, decode_ref
+from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.mamba2_ssd.kernel import ssd_chunk_scan
 from repro.kernels.mamba2_ssd.ref import ssd_ref
 from repro.kernels.moe_gmm.kernel import gmm, pad_groups
 from repro.kernels.moe_gmm.ref import gmm_ref
 from repro.kernels.rwkv6.kernel import wkv
 from repro.kernels.rwkv6.ref import wkv_ref
+from repro.models.layers import _sdpa_chunked
 
 KEY = jax.random.PRNGKey(0)
 
@@ -85,19 +86,64 @@ def test_flash_prefill_noncausal():
                                atol=2e-5, rtol=2e-5)
 
 
+def _decode_oracle(q, k, v, lengths, layer):
+    """The jnp path the kernel replaces on the stacked cache; an idle
+    slot (length 0) returns zeros."""
+    out = _sdpa_chunked(q[:, None], k[layer], v[layer], causal=True,
+                        q_offset=lengths - 1, window=None, chunk=512)[:, 0]
+    return jnp.where((lengths > 0)[:, None, None], out, 0)
+
+
+def _unread_as_nan(x, lengths, layer):
+    """x (L, B, T, Hkv, D) with NaN in every row the kernel must not
+    read: other layers' rows and rows at or past each slot's length."""
+    L_, _, T = x.shape[:3]
+    live = jnp.arange(T)[None, :] < lengths[:, None]             # (B, T)
+    keep = (jnp.arange(L_) == layer)[:, None, None] & live[None]
+    return jnp.where(keep[..., None, None], x, jnp.nan)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_decode_varlen(dtype):
     ks = jax.random.split(KEY, 3)
-    B, H, Hkv, T, D = 4, 8, 2, 256, 64
+    L_, B, H, Hkv, T, D = 2, 4, 8, 2, 256, 64
     q = jax.random.normal(ks[0], (B, H, D), dtype)
-    k = jax.random.normal(ks[1], (B, Hkv, T, D), dtype)
-    v = jax.random.normal(ks[2], (B, Hkv, T, D), dtype)
+    k = jax.random.normal(ks[1], (L_, B, T, Hkv, D), dtype)
+    v = jax.random.normal(ks[2], (L_, B, T, Hkv, D), dtype)
     lengths = jnp.array([1, 77, 128, 256], jnp.int32)
-    out = flash_attention_decode(q, k, v, lengths, block_k=64,
+    out = flash_attention_decode(q, k, v, lengths, 1, block_k=64,
                                  interpret=True)
-    ref = decode_ref(q, k, v, lengths)
+    assert out.dtype == dtype
+    ref = _decode_oracle(q, k, v, lengths, 1)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("rep", [2, 5])
+def test_flash_decode_reads_only_live_rows(rep, layer):
+    """Lengths 0, 1, block_k - 1, block_k, block_k + 1 and the whole
+    capacity in one batch, on the first and last layer of a stacked
+    cache: NaN in every row the kernel must not read leaves the output
+    equal to the jnp path's on the clean cache, and an idle slot reads
+    zeros."""
+    L_, Hkv, T, D, bk = 3, 2, 256, 128, 64
+    lengths = jnp.array([0, 1, bk - 1, bk, bk + 1, T, 0, 150], jnp.int32)
+    B = lengths.shape[0]
+    ks = jax.random.split(jax.random.PRNGKey(rep), 3)
+    q = jax.random.normal(ks[0], (B, Hkv * rep, D), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (L_, B, T, Hkv, D), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (L_, B, T, Hkv, D), jnp.bfloat16)
+    out = flash_attention_decode(
+        q, _unread_as_nan(k, lengths, layer),
+        _unread_as_nan(v, lengths, layer), lengths, layer, block_k=bk,
+        interpret=True)
+    out = np.asarray(out, np.float32)
+    assert np.isfinite(out).all()
+    assert not out[np.asarray(lengths) == 0].any()
+    np.testing.assert_allclose(
+        out, np.asarray(_decode_oracle(q, k, v, lengths, layer), np.float32),
+        **_tol(jnp.bfloat16))
 
 
 @settings(max_examples=10, deadline=None)

@@ -11,6 +11,9 @@ do not fit the chip's memory.  These tests compile, at real widths:
     that it updates its donated KV cache in place: the output aliases
     the cache, no temporary near its size, and no copy or
     dynamic-update-slice writes a whole new cache;
+  * the decode step of both benchmark configurations at 16 x 1024,
+    and check that it attends through the Pallas decode kernel from the
+    stacked cache, with no layer's cache staged whole;
   * the decode step and a 16 x 512 prefill of the benchmark's
     Qwen2.5-14B pipeline stage at its cell's 16 slots x 1024, and check
     that the arguments hold its published 40 query heads and fit;
@@ -145,12 +148,49 @@ def test_decode_updates_cache_in_place_for_v5e(one_chip):
     assert not rebuilt, rebuilt
 
 
+def _served_cfg(name):
+    """A benchmark configuration as ``DeploymentSpec.launch`` holds it:
+    no mesh axis splits heads."""
+    conf = json.loads((Path(__file__).resolve().parents[1] / "chipbench"
+                       / "configs" / f"{name}.json").read_text())
+    return conf, dataclasses.replace(harness.program_config(conf),
+                                     head_shards=1)
+
+
+@pytest.mark.parametrize("name", ["qwen3_1_7b", "qwen2_5_14b-12l"])
+def test_decode_reads_live_rows_in_place_for_v5e(one_chip, name):
+    """The donated decode step of each served configuration, at its
+    cell's 16 slots x 1024, attends through the Pallas kernel straight
+    from the stacked cache: no layer's cache is staged whole, and the
+    cache still updates in place."""
+    _, cfg = _served_cfg(name)
+    slots, max_len = 16, 1024
+    params = _on(one_chip, jax.eval_shape(lambda: M.init_params(cfg)))
+    cache = _on(one_chip, jax.eval_shape(
+        lambda: M.init_cache(cfg, slots, max_len)))
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32 = S((slots,), jnp.int32)
+    compiled = donating(decode_fused).lower(
+        params, cache, i32, i32, i32, S((slots,), jnp.bool_),
+        S((2,), jnp.uint32), cfg=cfg, eos=-1, temp=0.0,
+        max_len=max_len).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    layer = cache["kv"]["k"].shape[1:]
+    assert "bf16[1,{}]".format(",".join(map(str, layer))) not in text
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(l.size * l.dtype.itemsize
+                      for l in jax.tree_util.tree_leaves(cache))
+    assert mem.alias_size_in_bytes >= cache_bytes, mem
+    assert mem.temp_size_in_bytes < 64 * 2**20, mem
+
+
 @pytest.mark.parametrize("step", ["decode", "prefill"])
 def test_qwen25_stage_holds_published_heads_for_v5e(one_chip, step):
-    conf = json.loads((Path(__file__).resolve().parents[1] / "chipbench"
-                       / "configs" / "qwen2_5_14b-12l.json").read_text())
-    # as ``DeploymentSpec.launch`` holds it: no mesh axis splits heads
-    cfg = dataclasses.replace(harness.program_config(conf), head_shards=1)
+    conf, cfg = _served_cfg("qwen2_5_14b-12l")
     slots, max_len = 16, 1024              # the qwen25-14b-chat cell
     params = _on(one_chip, jax.eval_shape(lambda: M.init_params(cfg)))
     cache = _on(one_chip, jax.eval_shape(
@@ -188,10 +228,11 @@ def _kernel_cases(S):
             flash_attention_prefill,
             [S((1, 16, 2048, 128), bf), S((1, 8, 2048, 128), bf),
              S((1, 8, 2048, 128), bf)]),
+        # on the stacked (L, B, T, Hkv, D) cache of the qwen3-chat cell
         "flash_attention_decode": (
             flash_attention_decode,
-            [S((8, 16, 128), bf), S((8, 8, 2048, 128), bf),
-             S((8, 8, 2048, 128), bf), S((8,), i32)]),
+            [S((16, 16, 128), bf), S((28, 16, 1024, 8, 128), bf),
+             S((28, 16, 1024, 8, 128), bf), S((16,), i32), S((), i32)]),
         # mixtral-8x7B experts: d 4096, d_ff 14336
         "gmm": (gmm, [S((2048, 4096), bf), S((8, 4096, 14336), bf),
                       S((16,), i32)]),

@@ -151,12 +151,13 @@ assert match, "phase-split decode diverged from the single-engine run"
 
 # --- overlapped handoff: (layer, chunk) shards stream during prefill -- #
 # kv_chunks > 1 in the spec launches the STREAMED pairing:
-# prefill_handoff_stream yields each layer's KV for a chunk the moment
-# the chunk's prefill completes; admit_handoff_stream installs shards
-# eagerly and starts decoding when the last one lands.  On real
-# hardware the shard transfers ride the fabric concurrently with the
-# remaining prefill compute, so only the transfer tail lands in TTFT
-# (the engine analogue of simulate(kv_chunks=n) on the DES backend).
+# the prefill engine's sessions.stream yields each layer's KV for a
+# chunk the moment the chunk's prefill completes; the decode engine's
+# sessions.receive installs the shards eagerly and starts decoding when
+# the last one lands.  On real hardware the shard transfers ride the
+# fabric concurrently with the remaining prefill compute, so only the
+# transfer tail lands in TTFT (the engine analogue of
+# simulate(kv_chunks=n) on the DES backend).
 print("\n--- overlapped handoff (streamed (layer, chunk) shards) ---")
 streamed = requests_from_trace(pd_trace, cfg.vocab_size,
                                max_prompt=PROMPT_CAP, max_new=NEW_CAP,

@@ -21,19 +21,15 @@ vLLM-shaped control plane on a JAX data plane:
     disaggregated StagedExecutable, with the OnlineMonitor switching
     between latency- and throughput-oriented plans (examples/
     serve_pipeline.py wires this up end to end),
-  * prefill/decode disaggregation: ``prefill_handoff`` runs a prompt
-    and exports the per-request KV/recurrent state; ``admit_handoff``
-    on a second engine starts a decode_only session from the imported
-    state (greedy decode is bit-identical to a single-engine run) —
-    the real-engine analogue of the cluster simulator's KV-transfer
-    edge,
-  * PIPELINED handoff: ``prefill_handoff_stream`` processes the prompt
-    in ``prefill_chunk``-sized chunks and yields (layer, chunk) KV
-    shards as soon as they are computed, so the fabric transfer
-    overlaps the remaining prefill compute instead of starting only
-    after the whole prompt finishes; ``admit_handoff_stream`` installs
-    the shards eagerly and starts decoding the moment the last shard
-    lands (still bit-identical to the serial path),
+  * prefill/decode disaggregation through ``engine.sessions``
+    (kvpool.SessionManager): ``sessions.prefill`` runs a prompt and
+    packages the per-request KV/recurrent state, ``sessions.restore``
+    on a second engine starts decoding from it (greedy decode is
+    bit-identical to a single-engine run) — the real-engine analogue
+    of the cluster simulator's KV-transfer edge.  The PIPELINED pair
+    ``sessions.stream`` / ``sessions.receive`` ships (layer, chunk)
+    shards as soon as each prefill chunk completes, so the transfer
+    overlaps the remaining prefill compute,
   * chunked COLOCATED admission: with ``prefill_chunk`` set, a long
     admitted prompt no longer freezes the live decode slots for its
     whole prefill — decode steps are interleaved between prefill
@@ -69,8 +65,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.models import model as M
 from repro.models.config import ModelConfig
-from repro.serving.kvpool import (PagedKvCache, SessionManager,
-                                  SessionState)
+from repro.serving.kvpool import PagedKvCache, SessionManager
 
 # Families whose prefill is exact under right-padding (causal attention
 # never reads positions past the query).  Recurrent state (ssm/hybrid)
@@ -331,6 +326,62 @@ class ServingEngine:
             sub, logits / self.temperature, axis=-1))
 
     # ------------------------------------------------------------------ #
+    # Slot bookkeeping shared by admission, paged scheduling and the
+    # session movers (kvpool.SessionManager)
+    # ------------------------------------------------------------------ #
+    def _prefill_len(self, n: int) -> int:
+        """Prefill length for an ``n``-token prompt: pad-safe families
+        round up to a multiple of 8 (capped at ``max_len - 1``), so
+        admissions share compiled shapes; recurrent families keep the
+        exact length."""
+        if self.cfg.family in _PAD_SAFE_FAMILIES:
+            return min(-(-n // 8) * 8, self.max_len - 1)
+        return n
+
+    def _prefill_one(self, req: Request):
+        """Prefill ``req``'s prompt in a private batch-1 cache (no slot
+        consumed), through the injected prefill when there is one.
+        Returns (last-position logits, filled cache)."""
+        plen = len(req.prompt)
+        toks = np.zeros((1, self._prefill_len(plen)), np.int32)
+        toks[0, :plen] = req.prompt
+        cache1 = M.init_cache(self.cfg, 1, self.max_len)
+        if self._prefill_custom is not None:
+            return self._prefill_custom(
+                self.params, cache1, jnp.asarray(toks[:, :plen], jnp.int32))
+        return self._prefill(cache1, jnp.asarray(toks, jnp.int32),
+                             jnp.asarray([plen - 1], jnp.int32))
+
+    def _first_token(self, req: Request, logits,
+                     now: Optional[float]) -> Tuple[int, bool]:
+        """Sample ``req``'s first token once its prefill logits are
+        real and append it.  Returns (token, whether the session lives
+        on); a session that ends here (budget spent or EOS) is stamped
+        and finalized at the ready time."""
+        jax.block_until_ready(logits)
+        t_ready = self._now(now)
+        first = int(self._sample_host(logits)[0])
+        self.stats.prefill_batches += 1
+        req.output.append(first)
+        live = req.max_new_tokens > 1 and not (
+            self.eos_id is not None and first == self.eos_id)
+        if not live:
+            req.ttft = t_ready
+            self._finalize(req, t_ready)
+        return first, live
+
+    def _install(self, slot: int, req: Request, pos: int, last_tok: int,
+                 budget: int) -> None:
+        """Make ``req`` live in ``slot`` at the given decode cursor (its
+        state must already be in the slot's cache rows)."""
+        self.pos = self.pos.at[slot].set(pos)
+        self.last_tok = self.last_tok.at[slot].set(last_tok)
+        self.budget = self.budget.at[slot].set(budget)
+        self.active_mask = self.active_mask.at[slot].set(True)
+        self.active[slot] = req
+        self._ran[slot] = 0
+
+    # ------------------------------------------------------------------ #
     # Admission: batched multi-request prefill
     # ------------------------------------------------------------------ #
     def admit(self, req: Request, now: float) -> bool:
@@ -509,12 +560,7 @@ class ServingEngine:
         req = self._paged.resident[rid].req
         state, last_tok, pos, budget = self._paged.activate(rid, t)
         self.cache = M.import_kv(self.cfg, self.cache, slot, state)
-        self.pos = self.pos.at[slot].set(pos)
-        self.last_tok = self.last_tok.at[slot].set(last_tok)
-        self.budget = self.budget.at[slot].set(budget)
-        self.active_mask = self.active_mask.at[slot].set(True)
-        self.active[slot] = req
-        self._ran[slot] = 0
+        self._install(slot, req, pos, last_tok, budget)
 
     def _schedule(self, now: Optional[float]) -> None:
         """Round-robin time slicing at sync boundaries: parked
@@ -557,10 +603,7 @@ class ServingEngine:
         # ONLY sound for causal-attention families — recurrent state
         # integrates every input token, pads included — so ssm/hybrid
         # groups (already exact-length) keep their exact length.
-        if self.cfg.family in _PAD_SAFE_FAMILIES:
-            S = min(-(-max(lens) // 8) * 8, self.max_len - 1)
-        else:
-            S = max(lens)
+        S = self._prefill_len(max(lens))
         Gp = min(1 << (G - 1).bit_length(), self.slots)
         chunked = self._prefill_custom is None and self._can_chunk(S)
         with TraceAnnotation("engine.admit.prepare"):
@@ -665,65 +708,6 @@ class ServingEngine:
     def _chunk_call(self, cache, toks, off, rel):
         return self._prefill_at(cache, jnp.asarray(toks, jnp.int32),
                                 off, jnp.asarray(rel, jnp.int32))
-
-    # ------------------------------------------------------------------ #
-    # Legacy session-mover shims.  The implementation lives behind the
-    # unified ``engine.sessions`` facade (kvpool.SessionManager); these
-    # names remain for compatibility and translate to/from the old
-    # wire dicts with bit-identical tokens, errors, and TTFT stamps.
-    # New code should call ``engine.sessions`` directly.
-    # ------------------------------------------------------------------ #
-    def prefill_handoff(self, req: Request,
-                        now: Optional[float] = None) -> Dict[str, Any]:
-        """Deprecated shim over ``sessions.prefill``: run ``req``'s
-        prompt here and package the state for a decode-only peer as
-        the legacy handoff dict."""
-        return self.sessions.prefill(req, now).to_legacy()
-
-    def prefill_handoff_stream(self, req: Request,
-                               now: Optional[float] = None,
-                               chunk_size: Optional[int] = None):
-        """Deprecated shim over ``sessions.stream``: yields the legacy
-        per-(layer, chunk) shard dicts, then the header dict."""
-        for item in self.sessions.stream(req, now, chunk_size):
-            if isinstance(item, SessionState):
-                yield item.to_legacy(header=True)
-            else:
-                yield item.to_legacy()
-
-    def admit_handoff(self, req: Request, handoff: Dict[str, Any],
-                      now: Optional[float] = None) -> bool:
-        """Deprecated shim over ``sessions.restore`` with the first
-        token pending: TTFT is stamped on admission.  Raises on a
-        handoff that finished at prefill; returns False when no slot
-        is free."""
-        return self.sessions.restore(
-            req, SessionState.from_legacy(handoff,
-                                          first_token_pending=True),
-            now)
-
-    def admit_handoff_stream(self, req: Request, shards,
-                             now: Optional[float] = None) -> bool:
-        """Deprecated shim over ``sessions.receive`` (it accepts the
-        legacy shard dicts directly)."""
-        return self.sessions.receive(req, shards, now)
-
-    def export_sessions(self, now: Optional[float] = None
-                        ) -> List[Tuple[Request, Dict[str, Any]]]:
-        """Deprecated shim over ``sessions.checkpoint``: drain every
-        resident session as legacy (request, handoff-dict) pairs."""
-        return [(r, st.to_legacy())
-                for r, st in self.sessions.checkpoint(now)]
-
-    def import_session(self, req: Request, handoff: Dict[str, Any],
-                       now: Optional[float] = None) -> bool:
-        """Deprecated shim over ``sessions.restore`` with the first
-        token NOT pending: migration moves the session, not the
-        client's clock."""
-        return self.sessions.restore(
-            req, SessionState.from_legacy(handoff,
-                                          first_token_pending=False),
-            now)
 
     def warmup(self) -> None:
         """Prime the jitted prefill and fused decode step (the common

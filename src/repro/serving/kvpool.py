@@ -18,14 +18,12 @@ Two pieces, one file:
   greedy decode is bit-identical to never having been preempted.
 
 * **Session API** (`KvSlice`, `SessionState`, `SessionManager`): one
-  coherent surface over what used to be ten KV/session movers.
-  ``engine.sessions`` exposes ``prefill`` / ``stream`` / ``restore`` /
-  ``receive`` / ``checkpoint`` / ``migrate`` / ``prefetch``; the legacy
-  engine methods (``prefill_handoff{,_stream}``,
-  ``admit_handoff{,_stream}``, ``export_sessions`` /
-  ``import_session``) remain as thin deprecated shims that delegate
-  here and translate to the old wire dicts — bit-identical tokens,
-  same error messages, same TTFT accounting.
+  surface, ``engine.sessions``, for every move of a session's state:
+  ``prefill`` / ``stream`` / ``restore`` / ``receive`` / ``checkpoint``
+  / ``migrate`` / ``prefetch``, with typed values as the only wire
+  format.  The engine owns the slot bookkeeping these verbs share
+  (prefill length bucket, batch-1 prefill, first-token tail, slot
+  install); this module never imports the engine.
 """
 from __future__ import annotations
 
@@ -84,8 +82,8 @@ class KvSlice:
     checksum: Optional[int] = None      # producer-side kv_checksum
     # fabric priority class (serving.fabric.URGENT / .BULK): decode-
     # blocking handoffs travel URGENT; checkpoint/migration shards
-    # travel BULK.  Defaults URGENT so pre-fabric producers and the
-    # legacy wire format stay unchanged.
+    # travel BULK.  Defaults URGENT, so a producer that knows no
+    # fabric streams decode-blocking shards.
     klass: int = 0
 
     def verify(self) -> bool:
@@ -93,30 +91,15 @@ class KvSlice:
         return (self.checksum is None
                 or self.checksum == kv_checksum(self.state))
 
-    def to_legacy(self) -> Dict[str, Any]:
-        """The pre-facade stream-shard dict."""
-        return {"rid": self.rid, "key": self.component,
-                "layer": self.layer, "t0": self.t0, "t1": self.t1,
-                "state": self.state, "bytes": self.nbytes}
-
-    @classmethod
-    def from_legacy(cls, item: Dict[str, Any]) -> "KvSlice":
-        return cls(rid=item["rid"], component=item["key"],
-                   layer=item["layer"], t0=item.get("t0"),
-                   t1=item.get("t1"), state=item["state"],
-                   nbytes=item.get("bytes", 0))
-
 
 @dataclasses.dataclass
 class SessionState:
     """A session's portable decode state: the exported KV / recurrent
-    pytree plus the decode cursor.  ``first_token_pending`` encodes the
-    one behavioural difference between the old admit paths: True means
-    the first token has not streamed to the client yet, so
-    :meth:`SessionManager.restore` stamps TTFT on arrival (the old
-    ``admit_handoff``); False means the session already streamed
-    tokens elsewhere and migration must not touch the client's clock
-    (the old ``import_session``)."""
+    pytree plus the decode cursor.  ``first_token_pending`` True means
+    the first token has not streamed to the client yet (a prefill
+    handoff), so :meth:`SessionManager.restore` stamps TTFT on
+    arrival; False means the session already streamed tokens elsewhere
+    (a checkpoint) and moving it must not touch the client's clock."""
     rid: int
     state: Any                          # cache pytree; None when done
     last_tok: int
@@ -126,26 +109,6 @@ class SessionState:
     done: bool = False
     first_token_pending: bool = True
     priority: int = 0
-
-    def to_legacy(self, header: bool = False) -> Dict[str, Any]:
-        """The pre-facade handoff dict (``header=True`` marks the
-        final item of a shard stream)."""
-        d = {"rid": self.rid, "state": self.state,
-             "last_tok": self.last_tok, "pos": self.pos,
-             "budget": self.budget, "kv_bytes": self.nbytes,
-             "done": self.done}
-        if header:
-            d["header"] = True
-        return d
-
-    @classmethod
-    def from_legacy(cls, h: Dict[str, Any],
-                    first_token_pending: bool = True) -> "SessionState":
-        return cls(rid=h["rid"], state=h["state"],
-                   last_tok=h["last_tok"], pos=h["pos"],
-                   budget=h["budget"], nbytes=h["kv_bytes"],
-                   done=h["done"],
-                   first_token_pending=first_token_pending)
 
 
 # ===================================================================== #
@@ -427,21 +390,9 @@ class PagedKvCache:
 
     # ---------------------------------------------------------------- #
     def assemble(self, rid: int) -> Tuple[Any, int, int, int]:
-        """Reassemble a parked session's full state WITHOUT activating
-        it (the checkpoint/drain path) and release its blocks."""
-        ent = self.resident[rid]
-        assert ent.tier != "active", "active sessions export via slots"
-        if ent.tier == "host":
-            state = jax.tree_util.tree_map(jnp.asarray, ent.host)
-        elif self.token_paged and ent.payload is not None \
-                and ent.pos > 0 and "kv" not in ent.payload:
-            nb = -(-ent.pos // self.block_tokens)
-            state = dict(ent.payload)
-            state["kv"] = M.gather_kv_blocks(
-                self.arrays, ent.block_ids[:nb], ent.pos)
-        else:
-            state = ent.payload
-        out = (state, ent.last_tok, ent.pos, ent.budget)
+        """:meth:`peek`, then release the session's blocks (the
+        checkpoint/drain path)."""
+        out = self.peek(rid)
         self.release(rid)
         return out
 
@@ -468,29 +419,42 @@ class PagedKvCache:
 # ===================================================================== #
 # SessionManager: the one session-state API
 # ===================================================================== #
+def _prefilled(req, first: int, live: bool, state: Any,
+               nbytes: int) -> SessionState:
+    """The cursor a producer hands on after prefilling ``req``: a live
+    session waits for its first token to stream from the consumer; a
+    finished one (``done``) has nothing left to decode."""
+    return SessionState(
+        rid=req.rid, state=state, last_tok=first, pos=len(req.prompt),
+        budget=req.max_new_tokens - 1 if live else 0, nbytes=nbytes,
+        done=not live, first_token_pending=live,
+        priority=getattr(req, "priority", 0))
+
+
+def _checkpointed(req, state: Any, last_tok: int, pos: int,
+                  budget: int) -> SessionState:
+    """A resident session mid-decode, packaged to move: it keeps its
+    clock (``first_token_pending=False``)."""
+    return SessionState(
+        rid=req.rid, state=state, last_tok=last_tok, pos=pos,
+        budget=budget, nbytes=M.kv_state_bytes(state), done=False,
+        first_token_pending=False, priority=getattr(req, "priority", 0))
+
+
 class SessionManager:
-    """``engine.sessions`` — the unified surface over prefill handoff,
-    streamed shard handoff, live migration, and peer prefetch.
+    """``engine.sessions`` — the one surface over prefill handoff,
+    streamed shard handoff, live migration, and peer prefetch:
 
-    The legacy engine methods are thin shims over these six verbs:
-
-    ======================  =========================================
-    legacy                  facade
-    ======================  =========================================
-    prefill_handoff         ``prefill(req).to_legacy()``
-    prefill_handoff_stream  ``stream(req)`` (KvSlice / SessionState)
-    admit_handoff           ``restore(req, st)`` (first token pending)
-    admit_handoff_stream    ``receive(req, slices)``
-    export_sessions         ``checkpoint()``
-    import_session          ``restore(req, st)`` (token not pending)
-    ======================  =========================================
-
-    plus ``migrate(peer)`` (checkpoint -> peer.restore, loss-free),
-    ``prefetch(rid, peer)`` (pull ONE session off a peer engine — the
-    top of the HBM -> host -> peer cache hierarchy), and the
-    fault-tolerance pair ``snapshot()`` (non-destructive periodic
-    checkpoint read) / ``crash()`` (lose everything, state
-    unexported).
+    * ``prefill(req)`` -> :class:`SessionState` and ``stream(req)`` ->
+      :class:`KvSlice` shards then the cursor, on a producer;
+    * ``restore(req, st)`` and ``receive(req, slices)`` on a consumer;
+    * ``checkpoint()`` drains every resident session,
+      ``migrate(peer)`` (checkpoint -> peer.restore, loss-free) and
+      ``prefetch(rid, peer)`` (pull ONE session off a peer engine — the
+      top of the HBM -> host -> peer cache hierarchy);
+    * the fault-tolerance pair ``snapshot()`` (non-destructive periodic
+      checkpoint read) / ``crash()`` (lose everything, state
+      unexported).
     """
 
     def __init__(self, engine):
@@ -507,46 +471,14 @@ class SessionManager:
         sessions — it belongs to the engine that streams the first
         token (``restore`` with ``first_token_pending=True``)."""
         eng = self.eng
-        from repro.serving.engine import _PAD_SAFE_FAMILIES
         assert len(req.prompt) < eng.max_len, "prompt exceeds max_len"
-        plen = len(req.prompt)
-        if eng.cfg.family in _PAD_SAFE_FAMILIES:
-            S = min(-(-plen // 8) * 8, eng.max_len - 1)
-        else:
-            S = plen
-        toks = np.zeros((1, S), np.int32)
-        toks[0, :plen] = req.prompt
-        cache1 = M.init_cache(eng.cfg, 1, eng.max_len)
-        if eng._prefill_custom is not None:
-            logits, cache1 = eng._prefill_custom(
-                eng.params, cache1,
-                jnp.asarray(toks[:, :plen], jnp.int32))
-        else:
-            logits, cache1 = eng._prefill(
-                cache1, jnp.asarray(toks, jnp.int32),
-                jnp.asarray([plen - 1], jnp.int32))
-        jax.block_until_ready(logits)
-        t_ready = eng._now(now)
-        first = int(eng._sample_host(logits)[0])
-        eng.stats.prefill_batches += 1
-        req.output.append(first)
-        live = req.max_new_tokens > 1 and not (
-            eng.eos_id is not None and first == eng.eos_id)
+        logits, cache1 = eng._prefill_one(req)
+        first, live = eng._first_token(req, logits, now)
         if not live:        # done at prefill: nothing to hand off
-            req.ttft = t_ready
-            eng._finalize(req, t_ready)
-            return SessionState(
-                rid=req.rid, state=None, last_tok=first, pos=plen,
-                budget=0, nbytes=0, done=True,
-                first_token_pending=False,
-                priority=getattr(req, "priority", 0))
-        state = M.export_kv(eng.cfg, cache1, 0, plen)
-        return SessionState(
-            rid=req.rid, state=state, last_tok=first, pos=plen,
-            budget=req.max_new_tokens - 1,
-            nbytes=M.kv_state_bytes(state), done=False,
-            first_token_pending=True,
-            priority=getattr(req, "priority", 0))
+            return _prefilled(req, first, False, None, 0)
+        state = M.export_kv(eng.cfg, cache1, 0, len(req.prompt))
+        return _prefilled(req, first, True, state,
+                          M.kv_state_bytes(state))
 
     def stream(self, req, now: Optional[float] = None,
                chunk_size: Optional[int] = None,
@@ -567,11 +499,9 @@ class SessionManager:
         transfer scheduler between producer and consumer can order
         competing streams."""
         eng = self.eng
-        from repro.serving.engine import _PAD_SAFE_FAMILIES
         assert len(req.prompt) < eng.max_len, "prompt exceeds max_len"
         plen = len(req.prompt)
         C = chunk_size or eng.prefill_chunk or plen
-        cache1 = M.init_cache(eng.cfg, 1, eng.max_len)
         sent = 0
 
         def shard_item(key, layer, t0=None, t1=None):
@@ -586,6 +516,7 @@ class SessionManager:
 
         if (eng._prefill_custom is None
                 and eng.cfg.sliding_window is None and C < plen):
+            cache1 = M.init_cache(eng.cfg, 1, eng.max_len)
             toks = np.asarray(req.prompt, np.int32).reshape(1, plen)
             n_kv = M.cache_layer_counts(cache1).get("kv", 0)
             logits = None
@@ -601,21 +532,8 @@ class SessionManager:
             stream_kv_tail = False
         else:
             # serial fallback (ring-buffer SWA / injected prefill /
-            # single-chunk prompt): same bucketing as prefill()
-            if eng.cfg.family in _PAD_SAFE_FAMILIES:
-                S = min(-(-plen // 8) * 8, eng.max_len - 1)
-            else:
-                S = plen
-            toks = np.zeros((1, S), np.int32)
-            toks[0, :plen] = req.prompt
-            if eng._prefill_custom is not None:
-                logits, cache1 = eng._prefill_custom(
-                    eng.params, cache1,
-                    jnp.asarray(toks[:, :plen], jnp.int32))
-            else:
-                logits, cache1 = eng._prefill(
-                    cache1, jnp.asarray(toks, jnp.int32),
-                    jnp.asarray([plen - 1], jnp.int32))
+            # single-chunk prompt): the batch-1 prefill of prefill()
+            logits, cache1 = eng._prefill_one(req)
             stream_kv_tail = True
 
         for key, n_layers in M.cache_layer_counts(cache1).items():
@@ -629,27 +547,9 @@ class SessionManager:
                 sent += item.nbytes
                 yield item
 
-        jax.block_until_ready(logits)
-        t_ready = eng._now(now)
-        first = int(eng._sample_host(logits)[0])
-        eng.stats.prefill_batches += 1
-        req.output.append(first)
-        live = req.max_new_tokens > 1 and not (
-            eng.eos_id is not None and first == eng.eos_id)
-        if not live:            # done at prefill: producer finalizes
-            req.ttft = t_ready
-            eng._finalize(req, t_ready)
-            yield SessionState(
-                rid=req.rid, state=None, last_tok=first, pos=plen,
-                budget=0, nbytes=sent, done=True,
-                first_token_pending=False,
-                priority=getattr(req, "priority", 0))
-            return
-        yield SessionState(
-            rid=req.rid, state=None, last_tok=first, pos=plen,
-            budget=req.max_new_tokens - 1, nbytes=sent, done=False,
-            first_token_pending=True,
-            priority=getattr(req, "priority", 0))
+        # a session done at prefill is finalized here, on the producer
+        first, live = eng._first_token(req, logits, now)
+        yield _prefilled(req, first, live, None, sent)
 
     # ---------------------------------------------------------------- #
     # Consumer side: state lands on this engine, decode continues
@@ -661,14 +561,12 @@ class SessionManager:
         the first token streams only once the state lands); a migrated
         session keeps its original clock.  Returns False when no slot
         is free or (paged engines) the pool cannot fit the session
-        even after spilling — retry after draining."""
+        even after spilling — retry after draining.  A session that
+        finished at prefill has nothing to decode and is refused."""
         eng = self.eng
         if st.done:
-            if st.first_token_pending:
-                raise ValueError(
-                    f"request {st.rid} finished at prefill; "
-                    "there is no decode to admit")
-            raise AssertionError("finished session cannot migrate")
+            raise ValueError(f"request {st.rid} finished at prefill; "
+                             "there is no decode to admit")
         assert st.pos < eng.max_len, \
             "imported state exceeds this engine's max_len"
         eng.sync(now)
@@ -683,26 +581,20 @@ class SessionManager:
         eng.cache = M.import_kv(eng.cfg, eng.cache, slot, st.state)
         if st.first_token_pending:
             req.ttft = eng._now(now)
-        eng.pos = eng.pos.at[slot].set(st.pos)
-        eng.last_tok = eng.last_tok.at[slot].set(st.last_tok)
-        eng.budget = eng.budget.at[slot].set(st.budget)
-        eng.active_mask = eng.active_mask.at[slot].set(True)
-        eng.active[slot] = req
-        eng._ran[slot] = 0
+        eng._install(slot, req, st.pos, st.last_tok, st.budget)
         eng._recompute_remaining()
         return True
 
     def receive(self, req, slices,
                 now: Optional[float] = None) -> bool:
-        """Consume a :meth:`stream` (or legacy shard dicts): reserve a
-        slot, install every shard eagerly as it arrives, and start
-        decoding the moment the final :class:`SessionState` lands.
-        TTFT is stamped at that moment.  Returns False — without
-        consuming anything — when no slot (or, paged, no pool room)
-        is free.  A checksummed shard that fails :meth:`KvSlice.verify`
-        raises :class:`ShardChecksumError` AFTER the reserved
-        slot/blocks are rolled back, so the caller can re-prefill
-        locally instead."""
+        """Consume a :meth:`stream`: reserve a slot, install every
+        :class:`KvSlice` eagerly as it arrives, and start decoding the
+        moment the final :class:`SessionState` lands.  TTFT is stamped
+        at that moment.  Returns False — without consuming anything —
+        when no slot (or, paged, no pool room) is free.  A checksummed
+        shard that fails :meth:`KvSlice.verify` raises
+        :class:`ShardChecksumError` AFTER the reserved slot/blocks are
+        rolled back, so the caller can re-prefill locally instead."""
         eng = self.eng
         assert len(req.prompt) < eng.max_len, \
             "handoff prompt exceeds this engine's max_len"
@@ -738,35 +630,27 @@ class SessionManager:
                 pend, pend_win = [], None
 
         try:
-            for raw in slices:
-                if isinstance(raw, SessionState):
-                    header = raw
+            for item in slices:
+                if isinstance(item, SessionState):
+                    header = item
                     break
-                if isinstance(raw, KvSlice):
-                    if not raw.verify():
-                        raise ShardChecksumError(
-                            f"rid {raw.rid}: shard {raw.component}/"
-                            f"{raw.layer} arrived corrupted")
-                    item = raw.to_legacy()
-                else:
-                    item = raw
-                if item.get("header"):
-                    header = SessionState.from_legacy(item)
-                    break
-                win = (item.get("t0") or 0, item.get("t1"))
-                if (item["key"] == "kv"
+                if not item.verify():
+                    raise ShardChecksumError(
+                        f"rid {item.rid}: shard {item.component}/"
+                        f"{item.layer} arrived corrupted")
+                win = (item.t0 or 0, item.t1)
+                if (item.component == "kv"
                         and eng.cfg.sliding_window is None):
                     if pend and (pend_win != win or
-                                 item["layer"] !=
-                                 pend[0][0] + len(pend)):
+                                 item.layer != pend[0][0] + len(pend)):
                         flush()
-                    pend.append((item["layer"], item["state"]))
+                    pend.append((item.layer, item.state))
                     pend_win = pend_win or win
                     continue
                 flush()
                 eng.cache = M.import_kv_shard(
-                    eng.cfg, eng.cache, slot, item["key"],
-                    item["layer"], item["state"], win[0])
+                    eng.cfg, eng.cache, slot, item.component,
+                    item.layer, item.state, win[0])
             flush()
             assert header is not None, \
                 "handoff stream ended without header"
@@ -783,17 +667,57 @@ class SessionManager:
         assert header.pos < eng.max_len, \
             "imported state exceeds this engine's max_len"
         req.ttft = eng._now(now)
-        eng.pos = eng.pos.at[slot].set(header.pos)
-        eng.last_tok = eng.last_tok.at[slot].set(header.last_tok)
-        eng.budget = eng.budget.at[slot].set(header.budget)
-        eng.active_mask = eng.active_mask.at[slot].set(True)
-        eng._ran[slot] = 0
+        eng._install(slot, req, header.pos, header.last_tok,
+                     header.budget)
         eng._recompute_remaining()
         return True
 
     # ---------------------------------------------------------------- #
     # Whole-engine drain / migration / peer prefetch
     # ---------------------------------------------------------------- #
+    def _slot_cursors(self) -> Tuple[np.ndarray, ...]:
+        """Host copies of every slot's (pos, last_tok, budget)."""
+        eng = self.eng
+        return tuple(np.asarray(a) for a in
+                     (eng.pos, eng.last_tok, eng.budget))
+
+    def _from_slot(self, slot: int, cursors) -> SessionState:
+        """The session live in ``slot``, exported at its cursor."""
+        eng = self.eng
+        pos, last, budget = (int(a[slot]) for a in cursors)
+        state = M.export_kv(eng.cfg, eng.cache, slot, pos)
+        return _checkpointed(eng.active[slot], state, last, pos, budget)
+
+    def _free_slot(self, slot: int) -> None:
+        """Empty ``slot`` and release its session's pool blocks."""
+        eng = self.eng
+        rid = eng.active[slot].rid
+        eng.active[slot] = None
+        eng.active_mask = eng.active_mask.at[slot].set(False)
+        if eng._paged is not None:
+            eng._paged.release(rid)
+
+    def _residents(self, drain: bool) -> List[Tuple[Any, SessionState]]:
+        """Every resident session as (request, SessionState): live
+        slots first, then parked / spilled pool residents.  ``drain``
+        frees each slot and its blocks once read (:meth:`checkpoint`);
+        without it nothing changes (:meth:`snapshot`)."""
+        eng = self.eng
+        out: List[Tuple[Any, SessionState]] = []
+        if any(r is not None for r in eng.active):
+            cursors = self._slot_cursors()
+            for slot, req in enumerate(eng.active):
+                if req is not None:
+                    out.append((req, self._from_slot(slot, cursors)))
+                    if drain:
+                        self._free_slot(slot)
+        if eng._paged is not None:
+            read = eng._paged.assemble if drain else eng._paged.peek
+            for rid in eng._paged.parked():
+                preq = eng._paged.resident[rid].req
+                out.append((preq, _checkpointed(preq, *read(rid))))
+        return out
+
     def checkpoint(self, now: Optional[float] = None
                    ) -> List[Tuple[Any, SessionState]]:
         """Drain this engine loss-free: settle the buffered window,
@@ -801,40 +725,9 @@ class SessionManager:
         spilled pool residents — as (request, SessionState) with the
         decode cursor, and free all slots and blocks.  Sessions keep
         their clocks (``first_token_pending=False``)."""
-        eng = self.eng
-        eng.sync(now)
-        out: List[Tuple[Any, SessionState]] = []
-        if any(r is not None for r in eng.active):
-            pos = np.asarray(eng.pos)
-            last = np.asarray(eng.last_tok)
-            budget = np.asarray(eng.budget)
-            for slot in range(eng.slots):
-                req = eng.active[slot]
-                if req is None:
-                    continue
-                state = M.export_kv(eng.cfg, eng.cache, slot,
-                                    int(pos[slot]))
-                out.append((req, SessionState(
-                    rid=req.rid, state=state,
-                    last_tok=int(last[slot]), pos=int(pos[slot]),
-                    budget=int(budget[slot]),
-                    nbytes=M.kv_state_bytes(state), done=False,
-                    first_token_pending=False,
-                    priority=getattr(req, "priority", 0))))
-                eng.active[slot] = None
-                eng.active_mask = eng.active_mask.at[slot].set(False)
-                if eng._paged is not None:
-                    eng._paged.release(req.rid)
-        if eng._paged is not None:
-            for rid in eng._paged.parked():
-                preq = eng._paged.resident[rid].req
-                state, lt, p, b = eng._paged.assemble(rid)
-                out.append((preq, SessionState(
-                    rid=rid, state=state, last_tok=lt, pos=p,
-                    budget=b, nbytes=M.kv_state_bytes(state),
-                    done=False, first_token_pending=False,
-                    priority=getattr(preq, "priority", 0))))
-        eng._recompute_remaining()
+        self.eng.sync(now)
+        out = self._residents(drain=True)
+        self.eng._recompute_remaining()
         return out
 
     def snapshot(self, now: Optional[float] = None
@@ -847,36 +740,8 @@ class SessionManager:
         states are copies (``export_kv`` / :meth:`PagedKvCache.peek`
         slice fresh arrays), so later decode steps do not mutate a
         taken snapshot."""
-        eng = self.eng
-        eng.sync(now)
-        out: List[Tuple[Any, SessionState]] = []
-        if any(r is not None for r in eng.active):
-            pos = np.asarray(eng.pos)
-            last = np.asarray(eng.last_tok)
-            budget = np.asarray(eng.budget)
-            for slot in range(eng.slots):
-                req = eng.active[slot]
-                if req is None:
-                    continue
-                state = M.export_kv(eng.cfg, eng.cache, slot,
-                                    int(pos[slot]))
-                out.append((req, SessionState(
-                    rid=req.rid, state=state,
-                    last_tok=int(last[slot]), pos=int(pos[slot]),
-                    budget=int(budget[slot]),
-                    nbytes=M.kv_state_bytes(state), done=False,
-                    first_token_pending=False,
-                    priority=getattr(req, "priority", 0))))
-        if eng._paged is not None:
-            for rid in eng._paged.parked():
-                preq = eng._paged.resident[rid].req
-                state, lt, p, b = eng._paged.peek(rid)
-                out.append((preq, SessionState(
-                    rid=rid, state=state, last_tok=lt, pos=p,
-                    budget=b, nbytes=M.kv_state_bytes(state),
-                    done=False, first_token_pending=False,
-                    priority=getattr(preq, "priority", 0))))
-        return out
+        self.eng.sync(now)
+        return self._residents(drain=False)
 
     def crash(self, now: Optional[float] = None) -> List[Any]:
         """Hard-kill this engine's resident sessions WITHOUT exporting
@@ -909,33 +774,15 @@ class SessionManager:
         not hold it — the probe a peer prefetch uses."""
         eng = self.eng
         eng.sync(now)
-        for slot in range(eng.slots):
-            req = eng.active[slot]
-            if req is None or req.rid != rid:
-                continue
-            p = int(np.asarray(eng.pos)[slot])
-            state = M.export_kv(eng.cfg, eng.cache, slot, p)
-            st = SessionState(
-                rid=rid, state=state,
-                last_tok=int(np.asarray(eng.last_tok)[slot]), pos=p,
-                budget=int(np.asarray(eng.budget)[slot]),
-                nbytes=M.kv_state_bytes(state), done=False,
-                first_token_pending=False,
-                priority=getattr(req, "priority", 0))
-            eng.active[slot] = None
-            eng.active_mask = eng.active_mask.at[slot].set(False)
-            if eng._paged is not None:
-                eng._paged.release(rid)
-            eng._recompute_remaining()
-            return req, st
+        for slot, req in enumerate(eng.active):
+            if req is not None and req.rid == rid:
+                st = self._from_slot(slot, self._slot_cursors())
+                self._free_slot(slot)
+                eng._recompute_remaining()
+                return req, st
         if eng._paged is not None and eng._paged.holds(rid):
             preq = eng._paged.resident[rid].req
-            state, lt, p, b = eng._paged.assemble(rid)
-            return preq, SessionState(
-                rid=rid, state=state, last_tok=lt, pos=p, budget=b,
-                nbytes=M.kv_state_bytes(state), done=False,
-                first_token_pending=False,
-                priority=getattr(preq, "priority", 0))
+            return preq, _checkpointed(preq, *eng._paged.assemble(rid))
         return None
 
     def migrate(self, peer, now: Optional[float] = None) -> int:

@@ -685,12 +685,12 @@ class LaunchedDeployment:
 
     ``pd=False``: one continuous-batching :class:`ServingEngine`.
     ``pd=True``: a prefill engine + decode engine wired through the KV
-    handoff — serial ``prefill_handoff``/``admit_handoff`` export/
-    import, or streamed (layer, chunk) shards overlapping the
-    remaining prefill when ``kv_chunks > 1`` — the two-engine flow
-    that previously existed only as example code.  Greedy decode is
-    bit-identical to a single engine either way (asserted in
-    tests/test_deployment.py and examples/serve_pipeline.py).
+    handoff — serial ``sessions.prefill`` / ``sessions.restore``, or,
+    when ``kv_chunks > 1``, ``sessions.stream`` / ``sessions.receive``
+    with (layer, chunk) shards overlapping the remaining prefill.
+    Greedy decode is bit-identical to a single engine either way
+    (asserted in tests/test_deployment.py and
+    examples/serve_pipeline.py).
     """
 
     def __init__(self, spec: DeploymentSpec, cfg, params):
@@ -797,8 +797,8 @@ class LaunchedDeployment:
 
         ``remove``: engine indices that drain at ``at`` seconds into
         the run — the engine stops taking admissions, every resident
-        mid-decode session is exported (``export_sessions``) and
-        re-imported into a surviving engine (``import_session``), so
+        mid-decode session is checkpointed (``sessions.checkpoint``)
+        and restored on a surviving engine (``sessions.restore``), so
         no accepted request is dropped and greedy tokens are
         bit-identical to never having moved.  ``add``: device-name
         lists (cosmetic here — every launch engine runs the same local
@@ -898,16 +898,17 @@ class LaunchedDeployment:
             return
         g = act["group"]
         self._routable[g] = False     # no new admissions from here on
-        for req, h in self.engines[g].export_sessions(clk()):
-            self.wire_bytes += h["kv_bytes"]
+        for req, st in self.engines[g].sessions.checkpoint(clk()):
+            self.wire_bytes += st.nbytes
             self.migrations += 1
             while True:
                 tgt = self._pick_engine()
-                if tgt is not None and tgt.import_session(req, h, clk()):
+                if tgt is not None and tgt.sessions.restore(req, st,
+                                                            clk()):
                     ch = self._live_chan(g, self.engines.index(tgt))
                     if ch is not None:
                         from repro.serving.fabric import BULK
-                        ch.account(int(h["kv_bytes"]), BULK)
+                        ch.account(st.nbytes, BULK)
                     break
                 # every routable engine full: drain one decode step
                 # everywhere and retry — a slot frees in finitely many
@@ -1022,17 +1023,9 @@ class LaunchedDeployment:
         return out
 
     # ------------------------------------------------------------------ #
-    def _counted(self, gen):
-        for item in gen:
-            if not item.get("header"):
-                self.shards += 1
-                self.wire_bytes += item["bytes"]
-            yield item
-
     def _counted_native(self, gen):
-        """Shard accounting for a native ``sessions.stream`` (KvSlice /
-        SessionState items — the chaos-injected path keeps the typed
-        shards so checksums survive to the receiver)."""
+        """Count the shards of a ``sessions.stream`` as they pass (the
+        final ``SessionState`` cursor is not a shard)."""
         from repro.serving.kvpool import SessionState
         for item in gen:
             if not isinstance(item, SessionState):
@@ -1060,29 +1053,22 @@ class LaunchedDeployment:
             return time.perf_counter() - t0
 
         ordered = sorted(requests, key=lambda r: (r.arrival, r.rid))
+        from repro.serving.fabric import URGENT
+        # live fabric channel prefill -> decode: the handoff's bytes are
+        # counted as URGENT (decode-blocking) traffic
+        fabch = self._live_chan(0, 1)
         if self.spec.kv_chunks > 1:
+            from repro.serving.kvpool import ShardChecksumError
             link = (self._chaos.live_link(0, 1)
                     if self._chaos is not None else None)
-            # live fabric channel prefill -> decode: the handoff's
-            # shards are counted as URGENT (decode-blocking) traffic.
-            # The channel wraps OUTSIDE the chaos link, so it counts
-            # each shard as delivered once (retransmits inside the
-            # link are the link's own accounting, link.retries).
-            fabch = self._live_chan(0, 1)
             for req in ordered:
-                if link is None and fabch is None:
-                    gen = self._counted(
-                        pre.prefill_handoff_stream(req, clk()))
-                    while not dec.admit_handoff_stream(req, gen, clk()):
-                        dec.step(clk())     # drain a slot, retry
-                    continue
-                # chaos-injected and/or fabric-accounted handoff:
-                # typed shards (checksummed only under chaos) through
-                # the flaky channel.  Transient failures retransmit
-                # inside the link; a shard that exhausts its retries
-                # arrives corrupted and the receiver's checksum trips.
-                from repro.serving.fabric import URGENT
-                from repro.serving.kvpool import ShardChecksumError
+                # typed shards, checksummed only under chaos.  Through
+                # a flaky link, transient failures retransmit inside it
+                # and a shard that exhausts its retries arrives
+                # corrupted, so the receiver's checksum trips.  The
+                # fabric channel wraps OUTSIDE the link, so it counts
+                # each shard as delivered once (retransmits are the
+                # link's own accounting, link.retries).
                 shards = self._counted_native(
                     pre.sessions.stream(req, clk(),
                                         checksum=link is not None,
@@ -1107,19 +1093,17 @@ class LaunchedDeployment:
                 self.kv_retries = link.retries
                 self.kv_corrupted = link.corrupted
         else:
-            fabch = self._live_chan(0, 1)
-            handoffs: List[Tuple[Any, Dict]] = []
+            handoffs: List[Tuple[Any, Any]] = []
             for req in ordered:
-                h = pre.prefill_handoff(req, clk())
-                if not h["done"]:
-                    self.wire_bytes += h["kv_bytes"]
+                st = pre.sessions.prefill(req, clk())
+                if not st.done:
+                    self.wire_bytes += st.nbytes
                     if fabch is not None:
-                        from repro.serving.fabric import URGENT
-                        fabch.account(int(h["kv_bytes"]), URGENT)
-                    handoffs.append((req, h))
+                        fabch.account(st.nbytes, URGENT)
+                    handoffs.append((req, st))
             while handoffs:
-                while handoffs and dec.admit_handoff(
-                        handoffs[0][0], handoffs[0][1], clk()):
+                while handoffs and dec.sessions.restore(
+                        *handoffs[0], clk()):
                     handoffs.pop(0)
                 if handoffs:
                     dec.step(clk())

@@ -264,8 +264,9 @@ def test_live_scale_drains_loss_free_bit_identical():
 
 
 def test_live_export_import_direct_bit_identical():
-    """The migration primitives themselves: export mid-decode, import
-    on a fresh engine, finish — outputs match an unmigrated run."""
+    """The migration primitives themselves: sessions.checkpoint
+    mid-decode, sessions.restore on a fresh engine, finish — outputs
+    match an unmigrated run."""
     from repro.models import model as M
     from repro.serving.engine import Request, ServingEngine
     cfg = _smoke_cfg()
@@ -289,11 +290,11 @@ def test_live_export_import_direct_bit_identical():
     src.admit_batch(moved, 0.0)
     for _ in range(3):
         src.step(0.0)
-    handoffs = src.export_sessions(0.0)
+    handoffs = src.sessions.checkpoint(0.0)
     assert not src._any_active(), "export left residents behind"
-    for req, h in handoffs:
-        assert h["kv_bytes"] > 0 and not h["done"]
-        assert dst.import_session(req, h, 0.0)
+    for req, st in handoffs:
+        assert st.nbytes > 0 and not st.done
+        assert dst.sessions.restore(req, st, 0.0)
     while dst._any_active():
         dst.step(0.0)
     dst.sync(0.0)

@@ -508,6 +508,61 @@ def test_launch_matches_single_engine_all_backends():
     assert out["shards"] > 0
 
 
+# two islands joined by one crossing: the prefill -> decode handoff
+# rides a live fabric channel
+PD_FABRIC = {"islands": [{"name": "pre", "groups": [0]},
+                         {"name": "dec", "groups": [1]}],
+             "crossings": [{"src": "pre", "dst": "dec", "bw": 2e8,
+                            "latency": 50e-6, "duplex": "half"}],
+             "host_island": "pre"}
+
+
+@pytest.mark.parametrize("fabric", [None, PD_FABRIC],
+                         ids=["plain", "fabric"])
+def test_launch_streamed_pd_counts_every_shard(fabric):
+    """Streamed P/D through ``launch().run``, with and without a fabric
+    topology: greedy tokens equal one engine's, and ``wire_bytes`` and
+    ``shards`` count exactly the shards a prefill engine streams for
+    the same requests (the fabric charges the same bytes as URGENT)."""
+    from repro.models import model as M
+    from repro.serving.engine import Request, ServingEngine
+    from repro.serving.kvpool import KvSlice
+    cfg = _smoke("llama3_8b")
+    params = M.init_params(cfg)
+    rng = np.random.default_rng(11)
+    # with kv_chunks=8 over max_len=32 the prefill chunk is 4: two
+    # prompts stream chunk by chunk, the 3-token one in one piece
+    prompts = [rng.integers(0, cfg.vocab_size, size=n).astype(np.int32)
+               for n in (6, 3, 9)]
+
+    def mk():
+        return [Request(rid=i, prompt=p.copy(), max_new_tokens=5,
+                        arrival=0.0) for i, p in enumerate(prompts)]
+
+    singles = mk()
+    ServingEngine(cfg, params, slots=2, max_len=32, sync_every=2) \
+        .run(singles)
+    producer = ServingEngine(cfg, params, slots=2, max_len=32,
+                             prefill_chunk=4)
+    items = [it for r in mk() for it in producer.sessions.stream(r, 0.0)]
+    slices = [it for it in items if isinstance(it, KvSlice)]
+
+    reqs = mk()
+    out = DeploymentSpec(groups=[["h100"], ["l40s"]], pd=True,
+                         kv_chunks=8, arch="llama3_8b", fabric=fabric,
+                         engine={"slots": 2, "max_len": 32,
+                                 "sync_every": 2}).compile() \
+        .launch(cfg, params).run(reqs)
+    assert [r.output for r in reqs] == [r.output for r in singles]
+    assert out["shards"] == len(slices) > len(prompts)
+    assert out["wire_bytes"] == sum(s.nbytes for s in slices) > 0
+    if fabric is None:
+        assert "fabric" not in out
+    else:
+        assert out["fabric"]["urgent_bytes"] == out["wire_bytes"]
+        assert out["fabric"]["bulk_bytes"] == 0
+
+
 def test_launch_without_graph_simulate_raises():
     dep = DeploymentSpec(groups=[["h100"]], arch="llama3_8b").compile()
     with pytest.raises(ValueError, match="compile\\(graph\\)"):

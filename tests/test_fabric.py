@@ -463,9 +463,7 @@ def test_live_fabric_ckpt_accounting():
     assert st_["urgent_bytes"] == 0
 
 
-def test_kvslice_klass_default_and_legacy_roundtrip():
+def test_kvslice_klass_default():
     from repro.serving.kvpool import KvSlice
     sl = KvSlice(rid=1, component="kv", layer=0, nbytes=10)
-    assert sl.klass == URGENT                   # wire-compat default
-    # legacy dict format carries no class and restores the default
-    assert KvSlice.from_legacy(sl.to_legacy()).klass == URGENT
+    assert sl.klass == URGENT           # a fabric-unaware producer

@@ -15,7 +15,7 @@ Covers the pipelined-handoff acceptance criteria:
     any output token,
   * PDRouter decode-session affinity reuses the decode group's
     resident state for follow-up turns (transfers_avoided accounting),
-  * admit_handoff stamps wall-clock-mode times through the engine
+  * sessions.restore stamps wall-clock-mode times through the engine
     clock (regression: a literal 0.0 fallback).
 """
 import dataclasses
@@ -31,6 +31,7 @@ from repro.core.simulator import (KV_TRANSFER, Interconnect,
 from repro.models import model as M
 from repro.serving.cluster import TesseraCluster
 from repro.serving.engine import Request, ServingEngine
+from repro.serving.kvpool import KvSlice, SessionState
 from repro.serving.router import PDRouter
 from repro.serving.workload import poisson_trace
 
@@ -169,7 +170,7 @@ def test_mamba2_state_carries_across_chunks():
 # ===================================================================== #
 @pytest.mark.parametrize("arch", ARCHS)
 def test_streamed_handoff_bit_identical(arch):
-    """prefill_handoff_stream -> admit_handoff_stream must produce the
+    """sessions.stream -> sessions.receive must produce the
     same greedy tokens as a single engine that never split, for every
     family (ring-buffer SWA falls back to per-layer streaming)."""
     cfg = _smoke(arch)
@@ -192,22 +193,22 @@ def test_streamed_handoff_bit_identical(arch):
             for item in gen:
                 shards.append(item)
                 yield item
-        assert dec.admit_handoff_stream(
-            req, spy(pre.prefill_handoff_stream(req, 0.0, chunk_size=3)),
-            0.0)
+        assert dec.sessions.receive(
+            req, spy(pre.sessions.stream(req, 0.0, chunk_size=3)), 0.0)
         header = shards[-1]
-        assert header["header"] and not header["done"]
+        assert isinstance(header, SessionState) and not header.done
         # every pre-header item is a shard with a payload; chunked
         # families carry (layer, chunk) token ranges on their kv shards
         body = shards[:-1]
-        assert body and all("state" in it and it["bytes"] > 0
+        assert body and all(isinstance(it, KvSlice)
+                            and it.state is not None and it.nbytes > 0
                             for it in body)
-        kv = [it for it in body if it["key"] == "kv"]
+        kv = [it for it in body if it.component == "kv"]
         if kv and cfg.sliding_window is None:
             expect = {(t0, min(t0 + 3, len(req.prompt)))
                       for t0 in range(0, len(req.prompt), 3)}
-            assert {(it["t0"], it["t1"]) for it in kv} == expect
-        assert header["kv_bytes"] == sum(it["bytes"] for it in body)
+            assert {(it.t0, it.t1) for it in kv} == expect
+        assert header.nbytes == sum(it.nbytes for it in body)
     assert dec.stats.prefill_batches == 0
     while dec._any_active():
         dec.step(0.0)
@@ -226,8 +227,8 @@ def test_streamed_handoff_done_at_prefill_releases_slot():
         0, cfg.vocab_size, size=6).astype(np.int32), max_new_tokens=1)
     pre = ServingEngine(cfg, params, slots=1, max_len=16)
     dec = ServingEngine(cfg, params, slots=1, max_len=16)
-    assert dec.admit_handoff_stream(
-        req, pre.prefill_handoff_stream(req, 0.0, chunk_size=2), 0.0)
+    assert dec.sessions.receive(
+        req, pre.sessions.stream(req, 0.0, chunk_size=2), 0.0)
     assert dec.active == [None]              # slot released
     assert not dec._any_active()
     assert pre.stats.completed == 1 and len(req.output) == 1
@@ -244,12 +245,12 @@ def test_streamed_handoff_full_engine_rejects_without_consuming():
     pre = ServingEngine(cfg, params, slots=2, max_len=16)
     dec = ServingEngine(cfg, params, slots=1, max_len=16)
     first = mk(0)
-    assert dec.admit_handoff_stream(
-        first, pre.prefill_handoff_stream(first, 0.0, chunk_size=2), 0.0)
+    assert dec.sessions.receive(
+        first, pre.sessions.stream(first, 0.0, chunk_size=2), 0.0)
     blocked = mk(1)
-    gen = pre.prefill_handoff_stream(blocked, 0.0, chunk_size=2)
+    gen = pre.sessions.stream(blocked, 0.0, chunk_size=2)
     before = pre.stats.prefill_batches
-    assert not dec.admit_handoff_stream(blocked, gen, 0.0)
+    assert not dec.sessions.receive(blocked, gen, 0.0)
     assert pre.stats.prefill_batches == before   # generator untouched
     assert blocked.output == []
 
@@ -265,14 +266,13 @@ def test_streamed_handoff_oversized_releases_slot():
     pre = ServingEngine(cfg, params, slots=1, max_len=32)
     dec = ServingEngine(cfg, params, slots=1, max_len=16)   # too small
     with pytest.raises(AssertionError, match="max_len"):
-        dec.admit_handoff_stream(
-            big, pre.prefill_handoff_stream(big, 0.0, chunk_size=4),
-            0.0)
+        dec.sessions.receive(
+            big, pre.sessions.stream(big, 0.0, chunk_size=4), 0.0)
     assert dec.active == [None]              # slot not leaked
     ok = Request(rid=1, prompt=rng.integers(
         0, cfg.vocab_size, size=5).astype(np.int32), max_new_tokens=3)
-    assert dec.admit_handoff_stream(
-        ok, pre.prefill_handoff_stream(ok, 0.0, chunk_size=2), 0.0)
+    assert dec.sessions.receive(
+        ok, pre.sessions.stream(ok, 0.0, chunk_size=2), 0.0)
     while dec._any_active():
         dec.step(0.0)
     dec.sync(0.0)
@@ -311,8 +311,8 @@ def test_chunked_admission_interleaves_decode(arch):
     assert (out_a1, out_b1) == (out_a0, out_b0)
 
 
-def test_admit_handoff_uses_engine_clock_when_now_is_none():
-    """Regression: admit_handoff(now=None) must stamp wall-clock-mode
+def test_sessions_restore_uses_engine_clock_when_now_is_none():
+    """Regression: sessions.restore(now=None) must stamp wall-clock-mode
     times through the engine clock, not a literal 0.0."""
     cfg = _smoke("llama3_8b")
     params = M.init_params(cfg)
@@ -320,11 +320,11 @@ def test_admit_handoff_uses_engine_clock_when_now_is_none():
     req = Request(rid=0, prompt=rng.integers(
         0, cfg.vocab_size, size=5).astype(np.int32), max_new_tokens=3)
     pre = ServingEngine(cfg, params, slots=1, max_len=16)
-    h = pre.prefill_handoff(req, 0.0)
+    st = pre.sessions.prefill(req, 0.0)
     dec = ServingEngine(cfg, params, slots=1, max_len=16, sync_every=1)
     dec._clock = lambda: 7.5                 # wall-clock mode
     try:
-        assert dec.admit_handoff(req, h, now=None)
+        assert dec.sessions.restore(req, st, now=None)
         assert req.ttft == 7.5
         while dec._any_active():
             dec.step(None)

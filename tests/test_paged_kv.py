@@ -10,8 +10,9 @@ Covers the paged-KV acceptance criteria:
   * spill-to-host / prefetch round-trips equal the in-HBM decode,
   * priority preemption: a high-priority arrival displaces the
     lowest-priority resident; ``preempt_priority=False`` disables it,
-  * the ``engine.sessions`` facade (checkpoint / restore / migrate /
-    stream) matches the ten legacy movers bit-for-bit through shims,
+  * the ``engine.sessions`` facade (prefill / restore on a paged
+    engine, checkpoint / restore, migrate, stream / receive) decodes
+    bit-identically to a single engine that never moved a session,
   * DES mirror: ``KvPoolModel`` occupancy (delayed admission, LRU
     eviction, prefix/session cache hits), session-affinity ON strictly
     beating OFF on a multi-turn chat trace, kv_util reaching the
@@ -227,44 +228,42 @@ def test_priority_preempts_lowest_resident():
 
 
 # ===================================================================== #
-# The sessions facade vs the ten legacy movers
+# The sessions facade against a single engine that never moved
 # ===================================================================== #
 def test_facade_matches_legacy_handoff_bits():
-    """sessions.prefill/restore and prefill_handoff/admit_handoff are
-    the same machine: identical wire dicts, identical decode."""
+    """sessions.prefill -> restore between two PAGED engines decodes
+    bit-identically to one engine doing both; the restored sessions
+    reserve pool blocks on the decode side."""
     cfg = _smoke("llama3_8b")
     params = M.init_params(cfg)
     prompts = _prompts(cfg, (6, 4), seed=5)
 
-    legacy = _reqs(prompts)
-    pre_l = ServingEngine(cfg, params, slots=2, max_len=32)
-    dec_l = ServingEngine(cfg, params, slots=2, max_len=32, sync_every=2)
-    for r in legacy:
-        h = pre_l.prefill_handoff(r, 0.0)
-        assert set(h) == {"rid", "state", "last_tok", "pos", "budget",
-                          "kv_bytes", "done"}
-        assert dec_l.admit_handoff(r, h, 0.0)
-    dec_l.run([])
+    ref = _reqs(prompts)
+    ServingEngine(cfg, params, slots=2, max_len=32, sync_every=2) \
+        .run(ref)
 
-    facade = _reqs(prompts)
-    pre_f = ServingEngine(cfg, params, slots=2, max_len=32)
-    dec_f = ServingEngine(cfg, params, slots=2, max_len=32, sync_every=2)
-    for r in facade:
-        st_ = pre_f.sessions.prefill(r, 0.0)
+    moved = _reqs(prompts)
+    paged = dict(slots=2, max_len=32, kv_block_tokens=8)
+    pre = ServingEngine(cfg, params, **paged)
+    dec = ServingEngine(cfg, params, sync_every=2, **paged)
+    for r in moved:
+        st_ = pre.sessions.prefill(r, 0.0)
         assert isinstance(st_, SessionState)
-        assert set(st_.to_legacy()) == {"rid", "state", "last_tok",
-                                        "pos", "budget", "kv_bytes",
-                                        "done"}
-        assert dec_f.sessions.restore(r, st_, 0.0)
-    dec_f.run([])
-
-    for a, b in zip(legacy, facade):
+        assert st_.first_token_pending and not st_.done
+        assert st_.nbytes == M.kv_state_bytes(st_.state) > 0
+        assert dec.sessions.restore(r, st_, 0.0)
+        assert dec._paged.holds(r.rid)
+    _drain(dec)
+    assert dec.stats.prefill_batches == 0
+    assert dec._paged.pool.allocated == 0 and dec._paged.pool.check()
+    for a, b in zip(ref, moved):
         assert a.output == b.output
 
 
 def test_facade_checkpoint_restore_matches_export_import():
-    """sessions.checkpoint()/restore() == export_sessions()/
-    import_session() (same wire payloads through the shims)."""
+    """sessions.checkpoint() mid-decode, then sessions.restore() on a
+    fresh engine, decodes bit-identically to an engine that never
+    moved its sessions."""
     cfg = _smoke("llama3_8b")
     params = M.init_params(cfg)
     prompts = _prompts(cfg, (5, 3, 4), seed=6)
@@ -278,13 +277,13 @@ def test_facade_checkpoint_restore_matches_export_import():
     assert src.admit_batch(moved, 0.0) == 3
     src.step(0.0)
     src.step(0.0)
-    exported = src.export_sessions(0.0)          # legacy shim
+    exported = src.sessions.checkpoint(0.0)
     assert len(exported) == 3
     assert not src._any_active()
     dst = ServingEngine(cfg, params, slots=3, max_len=32, sync_every=2)
-    for r, h in ((next(r for r in moved if r.rid == h["rid"]), h)
-                 for _, h in exported):
-        assert dst.import_session(r, h, 0.0)     # legacy shim
+    for r, st_ in exported:
+        assert st_.rid == r.rid and not st_.first_token_pending
+        assert dst.sessions.restore(r, st_, 0.0)
     _drain(dst)
     for a, b in zip(ref, moved):
         assert a.output == b.output
@@ -315,8 +314,9 @@ def test_sessions_migrate_between_engines():
 
 
 def test_stream_receive_kvslice_and_legacy_dicts():
-    """sessions.stream() yields KvSlice/SessionState; sessions.receive()
-    accepts both the typed objects and their legacy dict encodings."""
+    """sessions.stream() yields KvSlice shards then the SessionState
+    cursor; sessions.receive() installs them and decodes
+    bit-identically to a single engine."""
     cfg = _smoke("llama3_8b")
     params = M.init_params(cfg)
     prompts = _prompts(cfg, (7, 5), seed=8)
@@ -333,10 +333,8 @@ def test_stream_receive_kvslice_and_legacy_dicts():
         items = list(pre.sessions.stream(r, 0.0))
         assert isinstance(items[-1], SessionState)
         assert all(isinstance(i, KvSlice) for i in items[:-1])
-        # round-trip every item through the legacy dict encoding
-        wire = [i.to_legacy(header=True) if isinstance(i, SessionState)
-                else i.to_legacy() for i in items]
-        assert dec.sessions.receive(r, wire, 0.0)
+        assert items[-1].nbytes == sum(i.nbytes for i in items[:-1])
+        assert dec.sessions.receive(r, iter(items), 0.0)
     dec.run([])
     for a, b in zip(ref, typed):
         assert a.output == b.output

@@ -299,9 +299,9 @@ def test_handoff_bit_identical_to_single_engine(arch):
     pre = ServingEngine(cfg, params, slots=2, max_len=32)
     dec = ServingEngine(cfg, params, slots=2, max_len=32, sync_every=2)
     for req in splits:
-        h = pre.prefill_handoff(req, 0.0)
-        assert h["kv_bytes"] > 0
-        assert dec.admit_handoff(req, h, 0.0)
+        st = pre.sessions.prefill(req, 0.0)
+        assert st.nbytes > 0
+        assert dec.sessions.restore(req, st, 0.0)
     while dec._any_active():
         dec.step(0.0)
     dec.sync(0.0)
@@ -330,7 +330,7 @@ def test_export_import_round_trips_cache_slot():
         np.asarray(cache["kv"]["v"][:, 1, :4]))
 
 
-def test_prefill_handoff_finishes_one_token_requests():
+def test_sessions_prefill_finishes_one_token_requests():
     """max_new_tokens=1 completes at prefill; the handoff is marked
     done (nothing to ship) and a decode engine rejects it loudly —
     a caller retrying it until admission would livelock."""
@@ -340,30 +340,30 @@ def test_prefill_handoff_finishes_one_token_requests():
     req = Request(rid=0, prompt=rng.integers(
         0, cfg.vocab_size, size=4).astype(np.int32), max_new_tokens=1)
     pre = ServingEngine(cfg, params, slots=1, max_len=16)
-    h = pre.prefill_handoff(req, 0.0)
-    assert h["done"]
-    assert h["kv_bytes"] == 0 and h["state"] is None
+    st = pre.sessions.prefill(req, 0.0)
+    assert st.done
+    assert st.nbytes == 0 and st.state is None
     assert pre.stats.completed == 1
     assert len(req.output) == 1
     dec = ServingEngine(cfg, params, slots=1, max_len=16)
     with pytest.raises(ValueError, match="finished at prefill"):
-        dec.admit_handoff(req, h, 0.0)
+        dec.sessions.restore(req, st, 0.0)
 
 
 def test_handoff_ttft_stamped_at_decode_admission():
     """TTFT accounting matches the simulator's KV-transfer edge: the
     first token streams only once the state lands on the decode
-    engine, so admit_handoff (not prefill_handoff) stamps it."""
+    engine, so sessions.restore (not sessions.prefill) stamps it."""
     cfg = _smoke("llama3_8b")
     params = M.init_params(cfg)
     rng = np.random.default_rng(5)
     req = Request(rid=0, prompt=rng.integers(
         0, cfg.vocab_size, size=5).astype(np.int32), max_new_tokens=4)
     pre = ServingEngine(cfg, params, slots=1, max_len=16)
-    h = pre.prefill_handoff(req, now=1.0)
+    st = pre.sessions.prefill(req, now=1.0)
     assert req.ttft == -1.0                  # not stamped yet
     dec = ServingEngine(cfg, params, slots=1, max_len=16)
-    assert dec.admit_handoff(req, h, now=3.5)
+    assert dec.sessions.restore(req, st, now=3.5)
     assert req.ttft == 3.5
 
 
